@@ -52,7 +52,7 @@ from repro import radon
 from repro.checkpoint.store import save_blob
 from repro.launch.router import ServiceRouter
 from repro.launch.service import DPRTService
-from repro.launch.supervisor import WorkerPool
+from repro.launch.supervisor import WorkerPool, refuse_chip_children
 
 from .common import emit
 
@@ -153,23 +153,21 @@ def main() -> None:
                 for fut in futs:
                     fut.result(timeout=300)
                 pool_walls.append(time.perf_counter() - t0)
-            ppool = min(pool_walls) / REQUESTS
             assert pool.verdict() == "OK", pool.healthz()
-            emit(f"serve/pool_workers2/N{N}/b{MAX_BATCH}", 1e6 * ppool,
-                 f"x_vs_router={ppool / rover:.2f} workers=2 "
-                 f"imgs_per_s={1 / ppool:.0f}", kind="serve",
-                 variant="pool_workers2", method="auto", n=N,
-                 batch=MAX_BATCH, requests=REQUESTS, guard_tol=3.0)
-        except Exception as e:
-            print(f"# serve/pool_workers2: skipped: {e}",
-                  file=sys.stderr)
         finally:
             pool.drain()
+        ppool = min(pool_walls) / REQUESTS
+        emit(f"serve/pool_workers2/N{N}/b{MAX_BATCH}", 1e6 * ppool,
+             f"x_vs_router={ppool / rover:.2f} workers=2 "
+             f"imgs_per_s={1 / ppool:.0f}", kind="serve",
+             variant="pool_workers2", method="auto", n=N,
+             batch=MAX_BATCH, requests=REQUESTS, guard_tol=3.0)
 
     # persistent AOT: cold start vs warm restart, each in a FRESH
     # process -- in-process re-compiles hit jax's lowering caches and
     # would flatter the "cold" number.  The warm child also asserts the
     # compile counters: a restore must take ZERO traces.
+    refuse_chip_children("serve/aot_cold_compile and aot_warm_restore")
     with tempfile.TemporaryDirectory() as d:
         op = radon.DPRT((MAX_BATCH, N, N), jnp.int32)
         save_blob(d, op.cache_token(), op.export_executable(),
@@ -198,22 +196,20 @@ def main() -> None:
                                  env=env, capture_output=True, text=True,
                                  timeout=300)
             if out.returncode != 0:
-                print(f"# serve/aot_{mode}: subprocess failed: "
-                      f"{out.stderr.strip()[-200:]}", file=sys.stderr)
-                return None
+                raise RuntimeError(f"serve/aot_{mode}: subprocess failed: "
+                                   f"{out.stderr.strip()[-2000:]}")
             return json.loads(out.stdout.strip().splitlines()[-1])["s"]
 
         cold, warm = restart("cold"), restart("warm")
-    if cold is not None and warm is not None:
-        emit(f"serve/aot_cold_compile/N{N}/b{MAX_BATCH}", 1e6 * cold,
-             f"x_vs_restore={cold / warm:.1f}", kind="serve",
-             variant="aot_cold_compile", method="auto", n=N,
-             batch=MAX_BATCH, guard_tol=2.5)
-        emit(f"serve/aot_warm_restore/N{N}/b{MAX_BATCH}", 1e6 * warm,
-             "fresh-process restore: deserialize only, zero traces, "
-             "no XLA compilation", kind="serve",
-             variant="aot_warm_restore", method="auto", n=N,
-             batch=MAX_BATCH, guard_tol=2.5)
+    emit(f"serve/aot_cold_compile/N{N}/b{MAX_BATCH}", 1e6 * cold,
+         f"x_vs_restore={cold / warm:.1f}", kind="serve",
+         variant="aot_cold_compile", method="auto", n=N,
+         batch=MAX_BATCH, guard_tol=2.5)
+    emit(f"serve/aot_warm_restore/N{N}/b{MAX_BATCH}", 1e6 * warm,
+         "fresh-process restore: deserialize only, zero traces, "
+         "no XLA compilation", kind="serve",
+         variant="aot_warm_restore", method="auto", n=N,
+         batch=MAX_BATCH, guard_tol=2.5)
 
 
 if __name__ == "__main__":

@@ -34,6 +34,8 @@ def main(argv=None) -> None:
                     help="run only the modules producing rows under this "
                          "baseline prefix (e.g. serve, conv, dprt_impl)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (table1_forward_cycles, table2_inverse_cycles,
                    table3_resources, fig17_runtime_vs_n, fig19_20_pareto,
                    bench_conv, bench_dprt_impl, bench_dprt_sharded,

@@ -52,11 +52,8 @@ from typing import Literal, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from .dprt import (accum_dtype_for, align_partial, is_prime, strip_partial)
 
@@ -80,15 +77,11 @@ BATCH_AXES = ("pod", "data")
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map without the replication checker: ``pallas_call`` has no
-    replication rule (jax asks for ``check_rep=False``), and the psum'd
-    outputs below are replicated by construction."""
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - newer jax renamed the flag
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+    """shard_map without the varying-manual-axes checker: ``pallas_call``
+    has no rule for it, and the psum'd outputs below are replicated by
+    construction."""
+    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 def _row_axis(mesh: Mesh) -> str:

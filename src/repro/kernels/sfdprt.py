@@ -73,31 +73,16 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.dprt import accum_dtype_for
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
-
-def _tpu_compiler_params(dimension_semantics):
-    """Compiler params across jax versions (CompilerParams vs
-    TPUCompilerParams spelling), None when unavailable."""
-    if pltpu is None:  # pragma: no cover
-        return None
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:  # pragma: no cover
-        return None
-    try:
-        return cls(dimension_semantics=dimension_semantics)
-    except Exception:  # pragma: no cover
-        return None
-
-
-_COMPILER_PARAMS = _tpu_compiler_params(("parallel", "parallel", "arbitrary"))
+# (batch, m-block) grid axes are independent; the innermost strip axis
+# accumulates into a resident output block, so it must run in order
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+_COMPILER_PARAMS_2D = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
 
 __all__ = [
     "skew_sum_pallas_raw",
@@ -539,9 +524,6 @@ def _pallas_stream_call(g: jnp.ndarray, *, sign: int, mode: str,
     (``stream_impl="dma"``, default off-interpret).  ``stream_rows`` is
     the streamed strip height H; VMEM footprint is O(m_block*N + H*N)
     per grid step regardless of ceil(N/H)."""
-    if pltpu is None:  # pragma: no cover - pltpu import failed
-        raise RuntimeError("streamed SFDPRT kernels need pallas TPU "
-                           "support (jax.experimental.pallas.tpu)")
     b, rows, n = g.shape
     acc_dtype = g.dtype
     h = max(1, min(int(stream_rows), rows))
@@ -566,7 +548,7 @@ def _pallas_stream_call(g: jnp.ndarray, *, sign: int, mode: str,
     else:
         # the operand never enters the BlockSpec pipeline: it stays in
         # HBM and the kernel DMAs strips on its own schedule
-        in_specs = [pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)]
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
     operands = [gp]
     with_offset = row_offset is not None
     if with_offset:
@@ -600,8 +582,7 @@ def _pallas_stream_call(g: jnp.ndarray, *, sign: int, mode: str,
         # exactly ONE double-buffer pair, however many strips stream
         scratch = [pltpu.VMEM((2, h, n_pad), acc_dtype),
                    pltpu.SemaphoreType.DMA((2,))]
-        cparams = None if interpret else _tpu_compiler_params(
-            ("parallel", "arbitrary"))
+        cparams = None if interpret else _COMPILER_PARAMS_2D
 
     return pl.pallas_call(
         kernel,
@@ -812,10 +793,11 @@ def _seg_roller(amt, n: int, n_pad: int, lb: int, rows_out: int,
 def _seg_roll_static(acc3: jnp.ndarray, k: int, n: int) -> jnp.ndarray:
     """Rotate every segment of a (rows, lb, n_pad) tile right by the
     *static* amount k at logical width n (zero tails carried through)."""
+    k %= n
     if k == 0:
         return acc3
-    return jnp.concatenate(
-        [acc3[:, :, n - k:n], acc3[:, :, :n - k], acc3[:, :, n:]], axis=2)
+    parts = [acc3[:, :, n - k:n], acc3[:, :, :n - k], acc3[:, :, n:]]
+    return jnp.concatenate([p for p in parts if p.shape[2]], axis=2)
 
 
 def _conv_epilogue(rf: jnp.ndarray, rg3: jnp.ndarray, n: int, n_pad: int,
@@ -827,6 +809,9 @@ def _conv_epilogue(rf: jnp.ndarray, rg3: jnp.ndarray, n: int, n_pad: int,
     K taps are consumed per cycle against K statically pre-rotated copies
     of the operand rows, so the loop body is K multiply-adds plus ONE
     static rotate-by-K of the accumulator -- no gathers, no index math.
+    The cycle's taps sit in lanes [0, K) of a carried copy of ``rf``
+    that rotates by K per cycle, so every lane index is static (Mosaic
+    lowers no dynamic lane slice of a value).
     """
     m_block = rf.shape[0]
     k = max(1, min(group, n - 1))
@@ -835,19 +820,23 @@ def _conv_epilogue(rf: jnp.ndarray, rg3: jnp.ndarray, n: int, n_pad: int,
     for _ in range(1, k):
         rgs.append(_seg_roll_static(rgs[-1], 1, n))
     nk = math.ceil(n / k)
-    if nk * k > n_pad:        # taps beyond the lane pad: zero (rf tail is 0)
-        rf3 = jnp.pad(rf3, ((0, 0), (0, 0), (0, nk * k - n_pad)))
+    width = max(n_pad, nk * k)
+    if width > n_pad:         # taps beyond the lane pad: zero (rf tail is 0)
+        rf3 = jnp.pad(rf3, ((0, 0), (0, 0), (0, width - n_pad)))
+    # cycle j consumes taps [t0, t0 + K) with t0 = (nk - 1 - j) * K: start
+    # rotated left by the first t0, then rotate right by K per cycle
+    taps = _seg_roll_static(rf3, width - (nk - 1) * k, width)
 
-    def body(j, acc):
-        t0 = (nk - 1 - j) * k
+    def body(_, carry):
+        acc, taps = carry
         acc = _seg_roll_static(acc, k, n)
-        fts = jax.lax.dynamic_slice(rf3, (0, 0, t0), (m_block, lb, k))
         for u in range(k):
-            acc = acc + fts[:, :, u:u + 1] * rgs[u]
-        return acc
+            acc = acc + taps[:, :, u:u + 1] * rgs[u]
+        return acc, _seg_roll_static(taps, k, width)
 
     acc = jnp.zeros((m_block, lb, n_pad), acc_dtype)
-    return jax.lax.fori_loop(0, nk, body, acc).reshape(m_block, lb * n_pad)
+    acc, _ = jax.lax.fori_loop(0, nk, body, (acc, taps))
+    return acc.reshape(m_block, lb * n_pad)
 
 
 def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
@@ -863,7 +852,7 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
     w_ref = refs.pop(0) if (op == "mul" or (op == "conv"
                                             and operand_form == "proj")) \
         else None
-    out_ref, aux_ref = refs
+    out_ref, aux_ref, rc_ref = refs
 
     mb = pl.program_id(1)
     zero = jnp.zeros((), acc_dtype)
@@ -916,19 +905,8 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
 
     # ---- per-direction epilogue ------------------------------------------
     def w_block3():
-        """This block's operand rows as (m_block, lb|1, n_pad).
-
-        In tail mode the operand block holds ALL direction rows (the
-        shard's window is traced), so slice at the global dir0; clamped
-        overreads only feed rows that are zero-masked through ``rf``.
-        """
-        width = wide if w_wide else n_pad
-        if source == "proj":
-            rows_w = jax.lax.dynamic_slice(w_ref[0], (dir0, 0),
-                                           (m_block, width))
-        else:           # blockspec already selected this m-block's rows
-            rows_w = w_ref[0]
-        rows_w = rows_w.astype(acc_dtype)
+        """This block's operand rows as (m_block, lb|1, n_pad)."""
+        rows_w = w_ref[0].astype(acc_dtype)
         if w_wide:
             return rows_w.reshape(m_block, lb, n_pad)
         return rows_w[:, None, :]
@@ -965,7 +943,9 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
     # tile height the dedicated inverse kernel tunes to): one (IB, wide)
     # accumulator + its gather index stay resident per sub-block instead
     # of a single (nr_pad, wide) mega-tile thrashing L2.
-    rcm = jnp.where(valid_fwd, rc, zero)
+    # staged in VMEM scratch: the Horner below reads one direction row
+    # per cycle at a traced index, which Mosaic lowers only from a ref
+    rc_ref[...] = jnp.where(valid_fwd, rc, zero)
     ib_rows = min(64, nr_pad)
     zs = []
     for i0 in range(0, nr_pad, ib_rows):
@@ -977,7 +957,7 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
         roll_inv = _seg_roller(neg_i, n, n_pad, lb, rows_ib, step_impl)
 
         def ibody(t, acc):
-            return roll_inv(acc) + rcm[m_block - 1 - t, :][None, :]
+            return roll_inv(acc) + rc_ref[pl.ds(m_block - 1 - t, 1), :]
 
         z = jax.lax.fori_loop(0, m_block, ibody,
                               jnp.zeros((rows_ib, wide), acc_dtype))
@@ -1112,35 +1092,34 @@ def pipeline_pallas_raw(f: jnp.ndarray, operand: jnp.ndarray | None = None,
         operands.append(gp.astype(acc_dtype))
     elif op == "mul" or (op == "conv" and operand_form == "proj"):
         wb = operand if operand.ndim == 3 else operand[None]
-        # pad the direction rows with m_block slack so the (traced) tail
-        # window slice stays in bounds; clamped overreads feed rows that
-        # are zero-masked through rf either way
-        w_rows = math.ceil((wb.shape[1] + m_block) / m_block) * m_block
+        if source == "proj":
+            # cut this shard's window of direction rows here, so the
+            # kernel reads one m-block of operand rows per grid step like
+            # the image source does (Mosaic loads a traced sublane window
+            # only at a provable 8-row alignment, which a shard's first
+            # direction need not have).  The zero slack keeps the window
+            # in bounds, unclamped; its rows meet masked directions only.
+            wb = jnp.pad(wb, ((0, 0), (0, rows_pad), (0, 0)))
+            wb = jax.lax.dynamic_slice_in_dim(
+                wb, jnp.asarray(0 if row_offset is None else row_offset,
+                                jnp.int32), rows_pad, axis=1)
+        w_rows = mb_total * m_block
         if wb.shape[0] == b and b > 1:
             w_wide = True
             wp = jnp.pad(wb, ((0, bg * lb - b), (0, w_rows - wb.shape[1]),
                               (0, n_pad - n)))
             wp = jnp.transpose(wp.reshape(bg, lb, w_rows, n_pad),
                                (0, 2, 1, 3)).reshape(bg, w_rows, wide)
-            if source == "proj":
-                in_specs.append(pl.BlockSpec((1, w_rows, wide),
-                                             lambda bb, i: (bb, 0, 0)))
-            else:
-                in_specs.append(pl.BlockSpec((1, m_block, wide),
-                                             lambda bb, i: (bb, i, 0)))
+            in_specs.append(pl.BlockSpec((1, m_block, wide),
+                                         lambda bb, i: (bb, i, 0)))
         else:
             wp = jnp.pad(wb[0], ((0, w_rows - wb.shape[1]),
                                  (0, n_pad - n)))[None]
-            if source == "proj":
-                in_specs.append(pl.BlockSpec((1, w_rows, n_pad),
-                                             lambda bb, i: (0, 0, 0)))
-            else:
-                in_specs.append(pl.BlockSpec((1, m_block, n_pad),
-                                             lambda bb, i: (0, i, 0)))
+            in_specs.append(pl.BlockSpec((1, m_block, n_pad),
+                                         lambda bb, i: (0, i, 0)))
         operands.append(wp.astype(acc_dtype))
 
-    cparams = None if interpret else _tpu_compiler_params(
-        ("parallel", "arbitrary"))
+    cparams = None if interpret else _COMPILER_PARAMS_2D
 
     out, aux = pl.pallas_call(
         functools.partial(
@@ -1155,6 +1134,7 @@ def pipeline_pallas_raw(f: jnp.ndarray, operand: jnp.ndarray | None = None,
                    pl.BlockSpec((1, 8, wide), lambda bb, i: (bb, 0, 0))),
         out_shape=(jax.ShapeDtypeStruct((bg, nr_pad, wide), acc_dtype),
                    jax.ShapeDtypeStruct((bg, 8, wide), acc_dtype)),
+        scratch_shapes=[pltpu.VMEM((m_block, wide), acc_dtype)],
         compiler_params=cparams,
         interpret=interpret,
     )(*operands)

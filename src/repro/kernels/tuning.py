@@ -152,8 +152,10 @@ def wasted_direction_rows(n: int, m_block: int, forward: bool = True) -> int:
 # iota setup outgrow L2); small primes are a single m-block.  On real
 # TPUs M bounds the accumulator sublanes ((M + N_pad_rows) * N_pad *
 # itemsize VMEM per step) -- re-measure on Mosaic before trusting these.
+# Every M is a multiple of 8: Mosaic tiles a block's second-minor axis in
+# 8-row sublane groups and refuses the operand blocks otherwise.
 PIPELINE_TUNE = {
-    61: (62, 4),
+    61: (64, 4),
     127: (64, 4),
     251: (64, 4),
     509: (64, 4),
@@ -168,8 +170,8 @@ def pipeline_block_spec(n: int, itemsize: int = 4) -> tuple[int, int]:
     if n in PIPELINE_TUNE:
         return PIPELINE_TUNE[n]
     _warn_off_table(n, PIPELINE_TUNE, _PIPELINE_FALLBACK_WARNED, "pipeline")
-    if n <= 61:
-        return n + 1, 4         # one m-block covers every direction row
+    if n <= 61:                 # one m-block covers every direction row
+        return math.ceil((n + 1) / 8) * 8, 4
     return 64, 4
 
 

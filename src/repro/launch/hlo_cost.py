@@ -27,15 +27,9 @@ __all__ = ["analyze_hlo", "compiled_cost_dict"]
 
 
 def compiled_cost_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized to a flat dict.
-
-    jaxlib<=0.4.x returns one dict per program (``[dict]``); newer
-    versions return the dict directly, or ``None`` on some backends.
-    """
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()`` as a dict (empty where the backend
+    reports none)."""
+    return compiled.cost_analysis() or {}
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,

@@ -62,6 +62,7 @@ from repro.configs.radon_251 import config as radon_config, \
 from repro.core.plan import available_backends, backend_capabilities, \
     get_backend
 from repro.data.synthetic import TokenStream, radon_images
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.service import (DPRTService, format_latency,
                                   latency_summary)
@@ -758,6 +759,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.list_backends:
         return list_backends()
+    enable_compile_cache()
     if args.mode == "lm":
         return serve_lm(args)
     if args.mode == "pool":

@@ -266,6 +266,11 @@ class DPRTService:
         """True once :meth:`warmup` has built the executables."""
         return bool(self._exes)
 
+    def executables(self) -> Dict[int, tuple]:
+        """Warm batch size -> the compiled executable chain serving it
+        (empty before :meth:`warmup`)."""
+        return dict(self._exes)
+
     def plans(self) -> set:
         """Every :class:`RadonPlan` the operator stages reference.
         Plans are SHARED across services of one geometry (forward and
@@ -444,9 +449,12 @@ class DPRTService:
         in-flight batch has completed."""
         while (self._queue is not None and not self._queue.empty()) \
                 or self._pending:
-            if self._pending:
-                await asyncio.gather(*list(self._pending),
-                                     return_exceptions=True)
+            # a gather of finished tasks returns without yielding to the
+            # loop, so the done-callbacks that empty _pending would never
+            # run: wait on running tasks only, else yield
+            running = [t for t in self._pending if not t.done()]
+            if running:
+                await asyncio.gather(*running, return_exceptions=True)
             else:
                 await asyncio.sleep(0)
 

@@ -54,12 +54,25 @@ from repro.launch.errors import (QueueFull, ServiceShutdown, WorkerLost,
 from repro.launch.pool import (RequestJournal, payload_digest, read_frame,
                                write_frame)
 
-__all__ = ["WorkerPool", "default_worker_cmd"]
+__all__ = ["WorkerPool", "default_worker_cmd", "refuse_chip_children"]
 
 #: error codes the pool books as typed rejections; anything else a
 #: worker reports ("internal", "bad_request") is a raw failure.
 _TYPED_CODES = ("deadline_exceeded", "queue_full", "shutdown",
                 "worker_lost", "service_error")
+
+
+def refuse_chip_children(what: str) -> None:
+    """Raise when this process runs JAX on a TPU: ``what`` starts child
+    processes that need the chip, and a chip serves one process at a
+    time -- this one holds it, so a child would fail or hang, and a
+    supervised child would crash-loop.  Workers get no device of their
+    own yet (ROADMAP R2)."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} starts child processes that need the TPU this "
+            f"process holds; refusing (one process per chip)")
 
 
 def default_worker_cmd(*, aot_dir: str, manifest: Sequence,
@@ -198,6 +211,9 @@ class WorkerPool:
                                   max_batch=self.max_batch)
 
     def start(self) -> "WorkerPool":
+        if self._cmd is None:
+            refuse_chip_children(f"a WorkerPool of {self.n_workers} "
+                                 f"serve workers")
         with self._lock:
             if self._started:
                 return self

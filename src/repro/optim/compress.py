@@ -14,11 +14,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 __all__ = ["quantize_int8", "dequantize_int8", "compress_tree",
            "compressed_psum_mean"]

@@ -525,3 +525,19 @@ def test_pool_of_real_workers_end_to_end(tmp_path):
         assert w["retraces_since_start"] == 0
     misses = sum(w["persistent"]["misses"] for w in report["workers"])
     assert misses == len(list_blobs(aot))   # coalesced cold start
+
+
+def test_pool_of_serve_workers_refused_on_a_chip_host(tmp_path, monkeypatch):
+    """A process that runs JAX on a TPU holds the chip, so serve workers
+    it would start could not reach it: the pool refuses to start them
+    (typed, before any spawn) instead of hanging or crash-looping."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = WorkerPool(2, aot_dir=str(tmp_path), manifest=[{"n": 5}])
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        pool.start()
+    assert all(w.proc is None for w in pool._workers)
+    # a worker command that is not a JAX serve worker is not refused
+    stub = stub_pool(1)
+    with stub:
+        assert stub.wait_ready(30.0)

@@ -228,7 +228,11 @@ def test_solve_datapath_serves_reconstructions():
 def test_reset_metrics_keeps_executables():
     imgs = _imgs(2)
     svc = DPRTService((N, N), jnp.int32, max_batch=2, max_wait_us=100.0)
+    assert svc.executables() == {}
     svc.warmup()
+    exes = svc.executables()
+    assert set(exes) == set(svc.sizes) and all(len(c) == 1
+                                               for c in exes.values())
     svc.run_requests(imgs)
     svc.reset_metrics()
     s = svc.stats()

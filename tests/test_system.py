@@ -1,6 +1,7 @@
 """End-to-end behaviour tests for the system (deliverable c)."""
 import json
 import os
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -33,6 +34,29 @@ def test_train_cli_smoke(tmp_path):
                 "--batch", "2", "--seq", "32",
                 "--ckpt-dir", str(tmp_path / "ck")])
     assert np.isfinite(out["last_loss"])
+
+
+def test_compile_cache_dir_is_fixed_or_from_env(monkeypatch):
+    from repro.launch.compile_cache import (DEFAULT_CACHE_DIR,
+                                            enable_compile_cache)
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs")}
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = enable_compile_cache()
+        assert DEFAULT_CACHE_DIR == Path(REPO).resolve() / ".jax_cache"
+        assert path == str(DEFAULT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert jax.config.jax_persistent_cache_min_compile_time_secs < 1.0
+        # where the variable is set, JAX reads it; the helper sets no dir
+        jax.config.update("jax_compilation_cache_dir", "/elsewhere")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == "/elsewhere"
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
 
 
 def test_serve_cli_radon_smoke():
