@@ -370,15 +370,9 @@ def main(argv=None) -> int:
               f"{dev.platform!r} ({len(devs)} device(s))", file=sys.stderr)
         return 1
 
+    from repro.core import spans     # counts compile-cache hits/misses
     from repro.launch.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache()
-    cache_events = {"hits": 0, "misses": 0}
-
-    def on_event(event, **_):
-        for key in cache_events:
-            if event == f"/jax/compilation_cache/cache_{key}":
-                cache_events[key] += 1
-    jax.monitoring.register_event_listener(on_event)
 
     log(f"platform={dev.platform} device_kind={dev.device_kind} "
         f"device_count={len(devs)} jax={jax.__version__}")
@@ -396,8 +390,9 @@ def main(argv=None) -> int:
     except SmokeFailure as e:
         print(f"chip_smoke: FAIL: {e}", file=sys.stderr)
         return 1
-    log(f"compile cache: hits={cache_events['hits']} "
-        f"misses={cache_events['misses']}")
+    counters = spans.snapshot()["counters"]
+    log(f"compile cache: hits={counters.get('compile_cache_hits', 0)} "
+        f"misses={counters.get('compile_cache_misses', 0)}")
     log(f"all phases passed in {time.perf_counter() - t0:.1f} s")
     print(json.dumps({"ok": True,
                       "device": {"platform": dev.platform,

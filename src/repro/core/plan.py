@@ -60,6 +60,7 @@ import jax.numpy as jnp
 from . import geometry as G
 from .dprt import (accum_dtype_for, align_partial, strip_partial,
                    _skew_sum_gather, _skew_sum_horner, _skew_sum_strips)
+from .spans import span
 from repro.kernels.tuning import resolve_blocks
 
 __all__ = [
@@ -1037,7 +1038,16 @@ def _cached_plan(shape: tuple, dtype_name: str, method: str,
                  block_batch: Optional[int], mesh) -> RadonPlan:
     key = (shape, dtype_name, method, strip_rows, m_block, batch_impl,
            block_rows, stream_rows, block_batch, mesh)
-    return _PLAN_CACHE.get_or_build(key, lambda: _build_plan(*key))
+
+    def build() -> RadonPlan:
+        # runs on a miss only; hits and misses stay in plan_cache_info()
+        with span("radon.plan", shape=shape, dtype=dtype_name,
+                  method=method, strip_rows=strip_rows, m_block=m_block,
+                  batch_impl=batch_impl, block_rows=block_rows,
+                  stream_rows=stream_rows, block_batch=block_batch,
+                  mesh=None if mesh is None else dict(mesh.shape)):
+            return _build_plan(*key)
+    return _PLAN_CACHE.get_or_build(key, build)
 
 
 def _build_plan(shape: tuple, dtype_name: str, method: str,

@@ -344,6 +344,7 @@ def _pallas_skew_call(g: jnp.ndarray, *, sign: int, mode: str,
                                        acc_dtype),
         compiler_params=None if interpret else _COMPILER_PARAMS,
         interpret=interpret,
+        name=f"sfdprt_{mode}",
     )(*operands)
 
 
@@ -594,6 +595,7 @@ def _pallas_stream_call(g: jnp.ndarray, *, sign: int, mode: str,
         scratch_shapes=scratch,
         compiler_params=cparams,
         interpret=interpret,
+        name=f"sfdprt_stream_{mode}",
     )(*operands)
 
 
@@ -1137,6 +1139,7 @@ def pipeline_pallas_raw(f: jnp.ndarray, operand: jnp.ndarray | None = None,
         scratch_shapes=[pltpu.VMEM((m_block, wide), acc_dtype)],
         compiler_params=cparams,
         interpret=interpret,
+        name=f"sfdprt_pipeline_{op}",
     )(*operands)
     return (_unpack_lanes(out, b, lb, n_pad),
             _unpack_lanes(aux, b, lb, n_pad)[:, :2])

@@ -62,6 +62,7 @@ from repro.kernels.tuning import router_warm_sizes
 from repro.launch.errors import (DeadlineExceeded, QueueFull, ServiceError,
                                  ServiceShutdown)
 from repro.launch.service import DPRTService, format_latency, latency_summary
+from repro.radon import healthz
 
 __all__ = ["ServiceRouter", "serve_jsonl"]
 
@@ -697,7 +698,8 @@ class ServiceRouter:
 
     def healthz(self) -> str:
         """The routed ``/healthz`` report: one verdict line, the
-        degradation ledger, per-route lines, latency + plan-cache."""
+        degradation ledger, per-route lines, latency + plan-cache, and
+        the process's set-up spans."""
         s = self.stats()
         rej = s["rejected"]
         lines = [
@@ -728,6 +730,7 @@ class ServiceRouter:
             "[healthz] plan_cache hits={hits} misses={misses} "
             "currsize={currsize} evictions={evictions}".format(
                 **s["plan_cache"]))
+        lines += healthz.span_lines()
         return "\n".join(lines)
 
     def __repr__(self) -> str:

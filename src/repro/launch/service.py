@@ -52,6 +52,7 @@ from repro.core.plan import plan_cache_entries, plan_cache_info
 from repro.kernels.tuning import nearest_warm_batch, warm_batch_sizes
 from repro.launch.errors import ServiceShutdown
 from repro.launch.faults import perturb
+from repro.radon import healthz
 
 __all__ = ["DPRTService", "latency_summary", "format_latency",
            "percentile"]
@@ -717,7 +718,9 @@ class DPRTService:
     def healthz(self) -> str:
         """The ``/healthz``-style report: one OK/FAIL verdict line, then
         admission, latency, and cache-counter lines (plan cache with its
-        eviction counter, trace counts, AOT + persistent executables)."""
+        eviction counter, trace counts, AOT + persistent executables),
+        then the process's set-up spans
+        (:func:`repro.radon.healthz.span_lines`)."""
         s = self.stats()
         verdict = "OK" if self.healthy() else "FAIL"
         lines = [
@@ -749,6 +752,7 @@ class DPRTService:
                 "[healthz] persistent_aot hits={hits} misses={misses} "
                 "errors={errors} degraded_compiles={degraded_compiles} "
                 "dir={directory}".format(**p))
+        lines += healthz.span_lines()
         return "\n".join(lines)
 
     def __repr__(self) -> str:

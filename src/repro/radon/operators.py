@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.core.dprt import accum_dtype_for
 from repro.core.plan import RadonPlan, add_plan_evict_hook, get_plan
+from repro.core.spans import span
 
 from . import ambient
 from .autodiff import (_CACHE_LOCK, INVERSE_OF, TRANSPOSE_OF, jitted_apply,
@@ -129,6 +130,29 @@ def _import_compiled(data: bytes):
     return _se.deserialize_and_load(payload, in_tree, out_tree)
 
 
+def _compile_once(op, key, kind: str, pins: tuple = ()):
+    """The executable under ``key`` in the process-wide AOT cache, built
+    on a miss only: a ``radon.compile`` span with two children,
+    ``radon.lower`` (trace and lowering, Pallas to Mosaic included) and
+    ``radon.backend_compile`` (an XLA compile or a load from JAX's
+    persistent cache).  ``pins`` stay alive with the entry."""
+    with _CACHE_LOCK:
+        exe = _AOT_CACHE.get(key)
+    if exe is not None:
+        return exe
+    with span("radon.compile", kind=kind, shape_in=op.shape_in,
+              dtype=op.dtype_in.name):
+        with span("radon.lower"):
+            lowered = op.lower()
+        with span("radon.backend_compile"):
+            built = lowered.compile()
+    with _CACHE_LOCK:
+        exe = _AOT_CACHE.setdefault(key, built)
+        if pins:    # keep id()-keyed arrays alive with the entry
+            _AOT_PINS.setdefault(key, pins)
+    return exe
+
+
 class PersistentAOTCache:
     """Disk-backed executable cache: ``jax.export``-style serialized AOT
     executables (via ``jax.experimental.serialize_executable``) keyed by
@@ -208,20 +232,21 @@ class PersistentAOTCache:
         from repro.checkpoint.store import load_blob, save_blob
         data = None
         had_blob = False
-        try:
-            data, meta = load_blob(self.directory, key)
-            had_blob = data is not None
-        except ValueError:              # torn/corrupt blob: overwrite
-            self.errors += 1
-            had_blob = True
-        if data is not None \
-                and meta.get("fingerprint") == aot_fingerprint():
+        with span("radon.aot_restore", token=key):
             try:
-                exe = op.import_executable(data)
-                self.hits += 1
-                return exe
-            except Exception:           # undeserializable: recompile
+                data, meta = load_blob(self.directory, key)
+                had_blob = data is not None
+            except ValueError:          # torn/corrupt blob: overwrite
                 self.errors += 1
+                had_blob = True
+            if data is not None \
+                    and meta.get("fingerprint") == aot_fingerprint():
+                try:
+                    exe = op.import_executable(data)
+                    self.hits += 1
+                    return exe
+                except Exception:       # undeserializable: recompile
+                    self.errors += 1
         self.misses += 1
         if had_blob:                    # blob existed but could not
             self.degraded_compiles += 1  # restore: degraded cold start
@@ -356,14 +381,7 @@ class RadonOperator:
         once per (plan, datapath, dtype) process-wide.  The returned
         executable is callable and never retraces -- the serve path's
         steady state."""
-        key = (self.plan, self.kind, self.dtype_in.name)
-        with _CACHE_LOCK:
-            exe = _AOT_CACHE.get(key)
-        if exe is None:
-            built = self.lower().compile()
-            with _CACHE_LOCK:
-                exe = _AOT_CACHE.setdefault(key, built)
-        return exe
+        return _compile_once(self, self._aot_key(), self.kind)
 
     # -- persistent AOT (executable export/import) -------------------------
     def cache_token(self) -> str:
@@ -558,15 +576,7 @@ class CompositeOperator:
         key = tuple(op._aot_key() for op in self.ops)
         pins = tuple(p for op in self.ops
                      for p in getattr(op, "_aot_pins", lambda: ())())
-        with _CACHE_LOCK:
-            exe = _AOT_CACHE.get(key)
-        if exe is None:
-            built = self.lower().compile()
-            with _CACHE_LOCK:
-                exe = _AOT_CACHE.setdefault(key, built)
-                if pins:    # keep id()-keyed arrays alive with the entry
-                    _AOT_PINS.setdefault(key, pins)
-        return exe
+        return _compile_once(self, key, "composite", pins)
 
     def as_matrix(self) -> jnp.ndarray:
         mats = [op.as_matrix() for op in self.ops]
@@ -843,15 +853,8 @@ class Conv2D:
         """The AOT-compiled executable for this (geometry, kernel),
         cached process-wide alongside the transform executables (the
         kernel array is pinned for the life of the entry)."""
-        key = self._aot_key()
-        with _CACHE_LOCK:
-            exe = _AOT_CACHE.get(key)
-        if exe is None:
-            built = self.lower().compile()
-            with _CACHE_LOCK:
-                exe = _AOT_CACHE.setdefault(key, built)
-                _AOT_PINS.setdefault(key, self._aot_pins())
-        return exe
+        return _compile_once(self, self._aot_key(), "conv2d",
+                             self._aot_pins())
 
     def cache_token(self) -> str:
         """Persistent-cache identity: like the transform operators',

@@ -64,9 +64,9 @@ from jax.custom_derivatives import linear_call
 from .autodiff import _CACHE_LOCK, _JITTED, _note_trace
 from .fusion import _is_zero_tangent
 from .masking import MaskedDPRT
-from .operators import (_AOT_CACHE, _AOT_PINS, _export_compiled,
-                        _import_compiled, _topology_token, DPRT,
-                        ProjectionFilter)
+from .operators import (_AOT_CACHE, _AOT_PINS, _compile_once,
+                        _export_compiled, _import_compiled,
+                        _topology_token, DPRT, ProjectionFilter)
 
 __all__ = ["METHODS", "SolveResult", "solve", "solve_operator",
            "ReconstructionOperator"]
@@ -557,15 +557,8 @@ class ReconstructionOperator:
         return jax.jit(self.__call__).lower(spec)
 
     def compile(self):
-        key = self._aot_key()
-        with _CACHE_LOCK:
-            exe = _AOT_CACHE.get(key)
-        if exe is None:
-            built = self.lower().compile()
-            with _CACHE_LOCK:
-                exe = _AOT_CACHE.setdefault(key, built)
-                _AOT_PINS.setdefault(key, self._aot_pins())
-        return exe
+        return _compile_once(self, self._aot_key(), "recon",
+                             self._aot_pins())
 
     def cache_token(self) -> str:
         import hashlib

@@ -7,7 +7,9 @@ steps, the DMA strip stream, the projection pipeline, the per-shard
 strip kernel) at real sizes for a *described* ``v5e:2x2`` topology:
 nothing runs, but the chip's compiler refuses here what it would refuse
 on the chip.  Each compile asserts a Mosaic kernel (``tpu_custom_call``)
-is in the program and prints ``memory_analysis()``.
+is in the program under its stable name (the ``name=`` of its
+``pallas_call``, which the profiler's trace shows) and prints
+``memory_analysis()``.
 
 The topology is described inside a module fixture, never at import: a
 process that describes it loads the TPU library and keeps its lock, so
@@ -54,9 +56,11 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *avals):
+def _compile(fn, *avals, name):
     compiled = jax.jit(fn).lower(*avals).compile()
-    assert "tpu_custom_call" in compiled.as_text(), "no Mosaic kernel"
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel"
+    assert name in text, f"no kernel named {name}"
     print(compiled.memory_analysis())
     return compiled
 
@@ -66,11 +70,13 @@ def test_fused_kernels_compile_n251_b16(one_chip, direction):
     n = 251
     if direction == "forward":
         aval = jax.ShapeDtypeStruct((16, n, n), jnp.int32, sharding=one_chip)
-        _compile(lambda f: dprt_pallas(f, interpret=False), aval)
+        _compile(lambda f: dprt_pallas(f, interpret=False), aval,
+                 name="sfdprt_forward")
     else:
         aval = jax.ShapeDtypeStruct((16, n + 1, n), jnp.int32,
                                     sharding=one_chip)
-        _compile(lambda r: idprt_pallas(r, interpret=False), aval)
+        _compile(lambda r: idprt_pallas(r, interpret=False), aval,
+                 name="sfdprt_inverse")
 
 
 @pytest.mark.parametrize("direction", ["forward", "inverse"])
@@ -79,12 +85,13 @@ def test_dma_stream_compiles_n2053(one_chip, direction):
     if direction == "forward":
         aval = jax.ShapeDtypeStruct((1, n, n), jnp.int32, sharding=one_chip)
         _compile(lambda f: dprt_pallas(f, stream_rows=rows, interpret=False),
-                 aval)
+                 aval, name="sfdprt_stream_forward")
     else:
         aval = jax.ShapeDtypeStruct((1, n + 1, n), jnp.int32,
                                     sharding=one_chip)
         _compile(lambda r: idprt_pallas(r, stream_rows=rows,
-                                        interpret=False), aval)
+                                        interpret=False), aval,
+                 name="sfdprt_stream_inverse")
 
 
 @pytest.mark.parametrize("n", [61, 251])
@@ -97,7 +104,8 @@ def test_pipeline_compiles(one_chip, op, n):
     rows = n if op == "conv" else n + 1
     w = jax.ShapeDtypeStruct((rows, n), jnp.int32, sharding=one_chip)
     _compile(lambda x, y: projection_pipeline_pallas(x, op, y,
-                                                     interpret=False), f, w)
+                                                     interpret=False), f, w,
+             name=f"sfdprt_pipeline_{op}")
 
 
 def test_sharded_strip_kernel_compiles_on_2x2(topo, monkeypatch):
@@ -112,6 +120,7 @@ def test_sharded_strip_kernel_compiles_on_2x2(topo, monkeypatch):
     aval = jax.ShapeDtypeStruct((16, n, n), jnp.int32,
                                 sharding=NamedSharding(mesh,
                                                        P("data", None, None)))
-    compiled = _compile(lambda f: dprt_sharded_pallas(f, mesh), aval)
+    compiled = _compile(lambda f: dprt_sharded_pallas(f, mesh), aval,
+                        name="sfdprt_forward")
     assert "reduce-scatter" in compiled.as_text() or \
         "all-reduce" in compiled.as_text()
