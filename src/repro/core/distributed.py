@@ -36,10 +36,10 @@ Two shard-local datapaths are registered in the transform plan registry
   explicit alignment gather.
 * ``"sharded_pallas"``  -- each device runs the fused SFDPRT Pallas
   kernel (:func:`repro.kernels.skew_sum_pallas_strip`) over its local
-  row strip or batch shard: the hoisted roll-select-ladder datapath of
-  PR 1 with the device's first global row folded into the alignment
-  ladder (one ``pallas_call`` per shard, batched stacks native).  All
-  four plan datapaths (forward / inverse / adjoint / inverse_adjoint)
+  row strip or batch shard: the strip kernels' datapath with the
+  device's first global row folded into the alignment ladder (one
+  ``pallas_call`` per shard, batched stacks native).  All four plan
+  datapaths (forward / inverse / adjoint / inverse_adjoint)
   ride this skew-sum, so ``jax.grad`` and ``op.T`` stay exact through
   the distributed path.  Declared mesh-aware with higher priority than
   ``"sharded"``, so ``method="auto"`` under a mesh resolves here.
@@ -318,9 +318,9 @@ def _sharded_pallas_partials(g: jnp.ndarray, mesh: Mesh, mode: str = "core",
     Rows of ``g`` (…, rows, N) shard over the mesh's row axis, a batch
     dim over its data axes.  Inside ``shard_map`` every device runs ONE
     fused Pallas kernel call over its local (B_local, rows_per_dev, N)
-    block: the hoisted binary roll-select-ladder datapath with the
-    device's first global row (``axis_index * rows_per_dev``, a traced
-    value) folded into the alignment ladder.  ``mode="core"`` computes
+    block: the strip kernels' datapath with the device's first global
+    row (``axis_index * rows_per_dev``, a traced value) folded into the
+    alignment ladder.  ``mode="core"`` computes
     the bare skew-sum partial; ``mode="forward"`` additionally fuses
     the R(N, d) row-sum epilogue in-kernel at global lane positions, so
     the full forward transform is exactly one kernel + one collective.

@@ -15,8 +15,9 @@ strategies that mirror the paper's architecture space:
   (one circular roll) and accumulated -- eq. (7)-(8) of the paper.
 * ``pallas``  -- the fused, batched Pallas TPU kernel family
   (:mod:`repro.kernels`): the strip decomposition mapped onto a
-  (batch, m-block, strip) grid with hoisted binary roll-select ladders
-  and the forward/inverse epilogues fused in-kernel; block shapes come
+  (batch, m-block, strip) grid whose Horner step is one native strided
+  lane rotate on a periodically extended accumulator, with the
+  forward/inverse epilogues fused in-kernel; block shapes come
   from the ``repro.kernels.tuning`` table unless given explicitly.
 * ``sharded`` / ``sharded_pallas`` -- the shard_map super-strip paths
   (:mod:`repro.core.distributed`); need ``mesh=``.  ``sharded_pallas``

@@ -148,9 +148,11 @@ def pareto_points(n: int, b: int) -> List[Dict[str, int]]:
 #
 # A (H-row strip) x (M-direction block) tile keeps in VMEM:
 #   strip rows        H  x Npad  x in_bytes
-#   accumulator       M  x Npad  x 4            (int32)
-#   per-step work: the binary roll-select ladder issues ceil(log2 N)
-#   roll+select pairs on the (M, Npad) accumulator plus one add.
+#   output block      M  x Npad  x 4            (int32, double-buffered)
+#   Horner carry      M  x L     x 4            (L = 2 * Npad)
+#   per-step work: one strided rotate, one static rotate, one select and
+#   one add on the (M, L) periodic extension; per strip, the alignment
+#   ladder's ceil(log2 N) roll+select pairs on (M, Npad).
 # ---------------------------------------------------------------------------
 @dataclass(frozen=True)
 class TPUStripCost:
@@ -169,10 +171,12 @@ def tpu_strip_cost(n: int, h: int, m_block: int, in_bytes: int = 4,
     n_pad = math.ceil(n / lanes) * lanes
     k = math.ceil(n / h)
     mb = math.ceil((n + 1) / m_block)
+    ext = 2 * n_pad
     ladder = max(1, _n(n))
-    vmem = h * n_pad * in_bytes + m_block * n_pad * 4 * 2  # strip + acc (dbl buf)
-    # per (strip, m-block): H steps x (ladder rolls + ladder selects + 1 add)
-    per_tile = h * (2 * ladder + 1) * m_block * n_pad
+    vmem = (h * n_pad * in_bytes + m_block * n_pad * 4 * 2
+            + m_block * ext * 4)
+    # per (strip, m-block): H steps x (2 rotates + 1 select + 1 add)
+    per_tile = h * 4 * m_block * ext
     align = (2 * ladder) * m_block * n_pad                 # alignment roll
     vpu = k * mb * (per_tile + align)
     hbm = k * mb * h * n_pad * in_bytes + (n + 1) * n_pad * 4

@@ -27,23 +27,31 @@ Dataflow (per grid step):
 * a block of M directions lives in the sublane axis of the accumulator,
 * each Horner step ``T <- row_i + roll(T, m)`` is the paper's single
   clock cycle: circular-shift registers + adder tree,
-* the per-direction roll amount m varies across sublanes, which TPUs
-  cannot shift natively; it is synthesized with a ceil(log2 N)-step
-  **binary roll-select ladder**: for each bit b of m, rotate the whole
-  tile by the *static* amount 2^b (two lane slices + concat -- no
-  gather, no index arithmetic) and select per sublane on bit b.
+* the roll amount m varies across sublanes, and it must wrap at the
+  logical N, not at the padded lane width.  The compiled step therefore
+  carries the accumulator as a **periodic extension**: on lanes
+  ``[n_pad, n_pad + N)`` of a ``2*n_pad`` lane tile, with a copy below
+  it.  A direction block's amounts are linear in the sublane
+  (``m0 + r``), so the per-direction roll is ONE native strided lane
+  rotate (``pltpu.roll(ext, m0, 1, stride=1, stride_axis=0)``) that
+  never leaves the periodic range, and after the row add one static
+  rotate and one select rebuild the copy -- four whole-tile ops a
+  cycle, none of them a gather or an unaligned lane slice,
+* the rotate moves lanes one way only (a stride of +1 per sublane), so
+  every mode computes the CRS skew sum (sign=-1); a forward skew sum's
+  direction m is the CRS direction <N - m>_N, and the wrapper reorders
+  the direction rows after the launch.
 
-**Hoisted ladder setup.**  The per-step roll amount is constant per
-direction across all H Horner steps, so all roll machinery -- for both
-the step roll and the alignment roll R'(r,m,d) = U_r(<d + m*rH>) of
-eq. (7) -- is precomputed ONCE per (m-block, strip) and closed over by
-the ``fori_loop`` body.  On the TPU ``"ladder"`` datapath that setup is
-the per-bit select masks (``(amt >> b) & 1``, :func:`ladder_select_masks`,
-<= ceil(log2 N) mask derivations + alignment rotate+select pairs per
-m-block); on the interpret/CPU ``"permute"`` lowering the permutations
-are materialized directly in index space and the alignment is ONE
-gather.  Nothing is re-derived on a Horner cycle; the loop body itself
-is the paper's pure shift-add datapath.
+**Hoisted setup.**  Everything a Horner cycle needs is derived ONCE per
+(m-block, strip) and closed over by the ``fori_loop`` body: the rotate's
+shift from the m-block's first direction, the upper-lane mask, and the
+eq. (7) alignment roll R'(r,m,d) = U_r(<d + m*rH>) -- which is
+not linear in the sublane once reduced mod N, runs once per strip, and
+stays a ceil(log2 N)-step **binary roll-select ladder**
+(:func:`apply_roll_ladder`, masks ``(amt >> b) & 1`` from
+:func:`ladder_select_masks`).  On the interpret/CPU ``"permute"``
+lowering the permutations are materialized in index space instead and
+the alignment is ONE gather.  Nothing is re-derived on a Horner cycle.
 
 **Shard-local partials.**  Every mode accepts ``rows < N`` inputs plus
 a (possibly traced) ``row_offset`` scalar operand: the mesh-distributed
@@ -52,9 +60,10 @@ over its local row super-strip, with the device's first global row
 folded into the alignment roll amount at zero extra datapath cost.
 
 **Lane padding.**  Off the interpret path the lane axis is padded to a
-multiple of 128 so Mosaic tiling is aligned; every ladder rotate slices
-at the *logical* N (``[s:n] ++ [:s] ++ [n:]``) so the circular wraparound
-stays exact and the zero tail is preserved.
+multiple of 128 so Mosaic tiling is aligned; the periodic extension and
+the alignment ladder (which slices at the *logical* N,
+``[s:n] ++ [:s] ++ [n:]``) keep the wraparound at N exact, and the lane
+tail is masked back to zero once per strip.
 
 **Masked final m-block.**  Direction rows beyond N-1 in the last m-block
 (the ``% N`` wrapped duplicates the seed kernel silently computed and
@@ -75,6 +84,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import spans
 from repro.core.dprt import accum_dtype_for
 
 # (batch, m-block) grid axes are independent; the innermost strip axis
@@ -117,9 +127,9 @@ def roll_rows_ladder_spec(n: int) -> int:
 def ladder_select_masks(amt: jnp.ndarray, n: int):
     """Hoisted ladder setup: per-bit select masks for a (M, 1) roll amount.
 
-    Computed once per m-block and closed over by the Horner loop body --
-    this is the "setup" the paper amortizes across all H cycles of a
-    strip (<= ceil(log2 N) shift+compare ops total, not per cycle).
+    Computed once per (m-block, strip), before the Horner loop, for the
+    strip kernels' alignment roll and the pipeline kernel's rolls
+    (<= ceil(log2 N) shift+compare ops total, never per cycle).
     """
     return [((amt >> b) & 1) == 1 for b in range(len(_ladder_rungs(n)))]
 
@@ -141,59 +151,111 @@ def apply_roll_ladder(acc: jnp.ndarray, masks, n: int) -> jnp.ndarray:
 
 
 def _strip_block_partial(read_row, *, h: int, n: int, n_pad: int,
-                         m_block: int, m_vec, valid, offset, sign: int,
+                         m_block: int, m0, m_vec, valid, offset,
                          step_impl: str, acc_dtype):
-    """Aligned, masked partial skew-sum of ONE H-row strip for one m-block.
+    """Aligned, masked partial CRS skew-sum of ONE H-row strip for one
+    m-block: ``out[r, d] = sum_j row_j(<d - m_r*(offset + j)>_N)`` with
+    ``m_r = m0 + r`` (``m_vec`` is the same, masked).
 
     This is the shared per-strip datapath of the fused (`_sfdprt_kernel`)
     and streamed (`_stream_grid_kernel` / `_stream_dma_kernel`) kernels:
     hoisted roll setup (per strip, not per cycle), H Horner cycles over
     ``read_row(j)`` (j = 0 is the strip's top row), the eq. (7)
     alignment roll for the strip's first global row ``offset`` (static
-    or traced), and the wrapped-duplicate row mask.  ``step_impl`` picks
-    the per-cycle roll realization (see :func:`_sfdprt_kernel`).
+    or traced), and the wrapped-duplicate row and lane-tail mask.
+    ``step_impl`` picks the per-cycle roll:
+
+    * ``"roll"`` (compiled) -- the loop carries the accumulator on lanes
+      ``[n_pad, n_pad + N)`` of an ``L = 2*n_pad`` lane tile whose lanes
+      ``[n_pad - N, n_pad)`` hold a second copy, so the tile is periodic,
+      ``ext[y] = acc[(y - n_pad) mod N]``, over ``(n_pad - N, n_pad + N)``.
+      Sublane ``r`` reads ``ext[y - m_r]``, which never leaves that
+      range: ONE native strided lane rotate by ``m0 + r`` (``pltpu.roll``
+      with ``stride=1`` over the sublanes).  Mosaic rotates each vreg's
+      8 sublanes exactly only while none moves past one vreg width: it
+      holds because ``m0`` is a multiple of 8 (Mosaic tiles an m-block
+      in 8 sublanes, or there is one block and ``m0 = 0``), and it is
+      why the rotate never runs backwards (a stride of ``L - 1``).
+      After the row add
+      (an aligned concat), one static rotate by ``L - N`` and one select
+      rebuild the copy for the next cycle.
+    * ``"permute"`` (interpret/CPU) -- the step and alignment
+      permutations materialized in index space once, one
+      ``take_along_axis`` per cycle (a gather is cheap there).
+
+    The alignment roll ``-m*offset mod N`` is not linear in the sublane
+    once reduced, and runs once per strip: the compiled path keeps it on
+    :func:`apply_roll_ladder`.
     """
     zero = jnp.zeros((), acc_dtype)
-    step_amt = m_vec if sign > 0 else (n - m_vec) % n
     # reduce the offset mod N before the multiply: streamed/sharded
     # offsets can exceed N (row padding), so m_vec * offset alone could
     # overflow int32 near the top-end N; with the reduction
     # m_vec * (offset % N) <= (N-1)^2 < 2^31 for every supported N
-    align_amt = jnp.mod(sign * m_vec * (offset % n), n)
+    align_amt = jnp.mod(-m_vec * (offset % n), n)
+    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (m_block, n_pad), 1)
 
     if step_impl == "permute":
-        lane_iota = jax.lax.broadcasted_iota(jnp.int32, (m_block, n_pad), 1)
         in_tail = lane_iota >= n
-        perm = jnp.where(in_tail, lane_iota, (lane_iota + step_amt) % n)
+        perm = jnp.where(in_tail, lane_iota,
+                         (lane_iota + (n - m_vec)) % n)
         align_perm = jnp.where(in_tail, lane_iota,
                                (lane_iota + align_amt) % n)
-    else:
-        step_sel = ladder_select_masks(step_amt, n)
-        align_sel = ladder_select_masks(align_amt, n)
 
-    def body(i, acc):
-        # T_i = f(i, .) + roll(T_{i+1}, sign*m): one "clock cycle" -- the
-        # roll consumes the precomputed masks/permutation.
-        if step_impl == "permute":
+        def body(i, acc):
+            # T_i = f(i, .) + roll(T_{i+1}, -m): one "clock cycle"
             acc = jnp.take_along_axis(acc, perm, axis=1)
-        else:
-            acc = apply_roll_ladder(acc, step_sel, n)
-        row = read_row(h - 1 - i)
-        return acc + row[None, :].astype(acc.dtype)
+            return acc + read_row(h - 1 - i)[None, :].astype(acc.dtype)
 
-    acc = jax.lax.fori_loop(0, h, body,
-                            jnp.zeros((m_block, n_pad), acc_dtype))
-
-    # alignment roll: R'(r, m, d) = U_r(<d + sign*m*rH>_n)   (eq. 7)
-    if step_impl == "permute":
+        acc = jax.lax.fori_loop(0, h, body,
+                                jnp.zeros((m_block, n_pad), acc_dtype))
+        # alignment roll: R'(r, m, d) = U_r(<d - m*rH>_n)   (eq. 7)
         acc = jnp.take_along_axis(acc, align_perm, axis=1)
     else:
-        acc = apply_roll_ladder(acc, align_sel, n)
-    return jnp.where(valid, acc, zero)
+        spans.count("sfdprt_step_roll")
+        ext_w = 2 * n_pad
+        upper = jax.lax.broadcasted_iota(jnp.int32,
+                                         (m_block, ext_w), 1) >= n_pad
+        below = jnp.zeros((1, n_pad), acc_dtype)
+        align_sel = ladder_select_masks(align_amt, n)
+
+        def body(i, ext):
+            # jnp.roll semantics: sublane r's lane y reads ext[y - m0 - r]
+            rot = pltpu.roll(ext, m0, 1, stride=1, stride_axis=0)
+            row = read_row(h - 1 - i)[None, :].astype(acc_dtype)
+            rot = rot + jnp.concatenate([below, row], axis=1)
+            return jnp.where(upper, rot, pltpu.roll(rot, ext_w - n, 1))
+
+        ext = jax.lax.fori_loop(0, h, body,
+                                jnp.zeros((m_block, ext_w), acc_dtype))
+        # lanes >= n of the slice are left over from the rotate: zeroed
+        # below
+        acc = apply_roll_ladder(ext[:, n_pad:], align_sel, n)
+    return jnp.where(jnp.logical_and(valid, lane_iota < n), acc, zero)
+
+
+def _mirror_directions(out: jnp.ndarray, n: int) -> jnp.ndarray:
+    """The kernels compute the CRS skew sum; its direction row p is the
+    forward (sign=+1) skew sum's direction <N - p>_N.  Reorder the
+    direction rows of a (B, R, lanes) kernel output to the forward's;
+    rows N and above (the fused row sum, masked rows) stay."""
+    return jnp.concatenate([out[:, :1], out[:, n - 1:0:-1], out[:, n:]],
+                           axis=1)
+
+
+def _resolve_step(step_impl: str | None, interpret: bool) -> str:
+    """The strip kernels' per-cycle step: ``"roll"`` compiled,
+    ``"permute"`` interpreted, unless the caller names one."""
+    if step_impl is None:
+        return "permute" if interpret else "roll"
+    if step_impl not in ("roll", "permute"):
+        raise ValueError(f"step_impl must be 'roll' or 'permute': "
+                         f"{step_impl!r}")
+    return step_impl
 
 
 def _sfdprt_kernel(f_ref, *rest, n: int, n_pad: int, h: int, m_block: int,
-                   sign: int, k_steps: int, mode: str, acc_dtype,
+                   k_steps: int, mode: str, acc_dtype,
                    step_impl: str, with_offset: bool = False):
     """One (batch, m-block, strip) grid step of the fused SFDPRT.
 
@@ -201,16 +263,10 @@ def _sfdprt_kernel(f_ref, *rest, n: int, n_pad: int, h: int, m_block: int,
     (batch, m-block) the output block stays resident while strips
     accumulate into it -- the paper's MEM_OUT (eq. 8).
 
-    ``step_impl`` picks how each Horner cycle realizes the hoisted roll:
-
-    * ``"ladder"``  -- re-apply the rotate+select ladder with the
-      precomputed masks every cycle (the TPU datapath: static lane
-      slices + per-sublane selects, no gathers -- Mosaic-friendly),
-    * ``"permute"`` -- materialize the step AND alignment permutations
-      directly in index space ONCE per m-block (setup only), then apply
-      one ``take_along_axis`` per cycle plus ONE for the eq. (7)
-      alignment (the interpret/CPU lowering, where a gather is cheap and
-      per-cycle -- or per-short-strip -- ladder passes are not).
+    ``step_impl`` picks how each Horner cycle realizes the roll: the
+    native strided rotate on a periodically extended accumulator
+    (``"roll"``, compiled) or a hoisted index-space permutation
+    (``"permute"``, interpret) -- see :func:`_strip_block_partial`.
 
     ``with_offset`` threads a (1, 1) scalar operand holding the strip's
     first *global* image row (the mesh-sharded path: each device's local
@@ -241,7 +297,7 @@ def _sfdprt_kernel(f_ref, *rest, n: int, n_pad: int, h: int, m_block: int,
         offset = offset + off_ref[0, 0]       # first global image row
     acc = _strip_block_partial(
         lambda j: f_ref[0, j, :], h=h, n=n, n_pad=n_pad, m_block=m_block,
-        m_vec=m_vec, valid=valid, offset=offset, sign=sign,
+        m0=mb * m_block, m_vec=m_vec, valid=valid, offset=offset,
         step_impl=step_impl, acc_dtype=acc_dtype)
 
     @pl.when(k == 0)
@@ -292,14 +348,16 @@ def _pallas_skew_call(g: jnp.ndarray, *, sign: int, mode: str,
     accumulator dtype (rows == N for whole images; rows < N for a
     shard-local row strip); returns (B, R, n_pad) with
     R = ceil(out_rows/m_block)*m_block -- callers slice to the logical
-    output.
+    output.  The kernel computes the CRS (sign=-1) skew sum; a forward
+    (``sign=+1``) call reorders its direction rows after the launch
+    (:func:`_mirror_directions`), one pass over the output in XLA.
 
     ``lane_pad`` (default: pad iff compiled) rounds the lane axis up to a
     128-multiple for Mosaic tile alignment; it is overridable so the
     wraparound-at-logical-N path is testable in interpret mode.
-    ``step_impl`` (default: "permute" in interpret mode, "ladder"
+    ``step_impl`` (default: "permute" in interpret mode, "roll"
     compiled) picks the per-cycle roll realization -- see
-    :func:`_sfdprt_kernel`.  ``row_offset`` (static or traced scalar)
+    :func:`_strip_block_partial`.  ``row_offset`` (static or traced scalar)
     is the first *global* image row of ``g``'s row block -- the
     shard-local partial of the mesh path; it feeds the alignment ladder
     only (core mode).
@@ -310,8 +368,7 @@ def _pallas_skew_call(g: jnp.ndarray, *, sign: int, mode: str,
     k_steps = math.ceil(rows / h)
     if lane_pad is None:
         lane_pad = not interpret
-    if step_impl is None:
-        step_impl = "permute" if interpret else "ladder"
+    step_impl = _resolve_step(step_impl, interpret)
     n_pad = ((n + LANE - 1) // LANE) * LANE if lane_pad else n
     out_rows = n + 1 if mode == "forward" else n
     r_blocks = math.ceil(out_rows / m_block)
@@ -331,9 +388,9 @@ def _pallas_skew_call(g: jnp.ndarray, *, sign: int, mode: str,
                                      lambda bb, i, j: (bb, i, 0)))
         operands.append(corr_p)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_sfdprt_kernel, n=n, n_pad=n_pad, h=h,
-                          m_block=m_block, sign=sign, k_steps=k_steps,
+                          m_block=m_block, k_steps=k_steps,
                           mode=mode, acc_dtype=acc_dtype,
                           step_impl=step_impl, with_offset=with_offset),
         grid=(b, r_blocks, k_steps),
@@ -346,6 +403,7 @@ def _pallas_skew_call(g: jnp.ndarray, *, sign: int, mode: str,
         interpret=interpret,
         name=f"sfdprt_{mode}",
     )(*operands)
+    return _mirror_directions(out, n) if sign > 0 else out
 
 
 # ===========================================================================
@@ -380,7 +438,7 @@ def _pallas_skew_call(g: jnp.ndarray, *, sign: int, mode: str,
 
 
 def _stream_grid_kernel(f_ref, *rest, n: int, n_pad: int, h: int,
-                        m_block: int, sign: int, k_steps: int, mode: str,
+                        m_block: int, k_steps: int, mode: str,
                         acc_dtype, step_impl: str, with_offset: bool):
     """One (batch, m-block, strip) step of the streamed kernel, strip loop
     on the grid: partial skew-sums accumulate in a VMEM scratch tile and
@@ -403,7 +461,7 @@ def _stream_grid_kernel(f_ref, *rest, n: int, n_pad: int, h: int,
 
     acc = _strip_block_partial(
         lambda j: f_ref[0, j, :], h=h, n=n, n_pad=n_pad, m_block=m_block,
-        m_vec=m_vec, valid=valid, offset=offset, sign=sign,
+        m0=mb * m_block, m_vec=m_vec, valid=valid, offset=offset,
         step_impl=step_impl, acc_dtype=acc_dtype)
 
     @pl.when(k == 0)
@@ -441,7 +499,7 @@ def _stream_grid_kernel(f_ref, *rest, n: int, n_pad: int, h: int,
 
 
 def _stream_dma_kernel(f_ref, *rest, n: int, n_pad: int, h: int,
-                       m_block: int, sign: int, k_steps: int, mode: str,
+                       m_block: int, k_steps: int, mode: str,
                        acc_dtype, step_impl: str, with_offset: bool):
     """One (batch, m-block) step of the streamed kernel, strip loop in
     the kernel: the operand stays in HBM (``memory_space=ANY``) and the
@@ -481,8 +539,8 @@ def _stream_dma_kernel(f_ref, *rest, n: int, n_pad: int, h: int,
         offset = k * h + off0
         acc = acc + _strip_block_partial(
             lambda j: buf_ref[slot, j, :], h=h, n=n, n_pad=n_pad,
-            m_block=m_block, m_vec=m_vec, valid=valid, offset=offset,
-            sign=sign, step_impl=step_impl, acc_dtype=acc_dtype)
+            m_block=m_block, m0=mb * m_block, m_vec=m_vec, valid=valid,
+            offset=offset, step_impl=step_impl, acc_dtype=acc_dtype)
         if mode == "forward":
             # fused R(N, d) epilogue while the strip is VMEM-resident;
             # mb is traced here (loop-carried value, not a ref), so the
@@ -531,8 +589,7 @@ def _pallas_stream_call(g: jnp.ndarray, *, sign: int, mode: str,
     k_steps = math.ceil(rows / h)
     if lane_pad is None:
         lane_pad = not interpret
-    if step_impl is None:
-        step_impl = "permute" if interpret else "ladder"
+    step_impl = _resolve_step(step_impl, interpret)
     if stream_impl is None:
         stream_impl = "grid" if interpret else "dma"
     if stream_impl not in ("grid", "dma"):
@@ -566,9 +623,9 @@ def _pallas_stream_call(g: jnp.ndarray, *, sign: int, mode: str,
             else (lambda bb, i: (bb, i, 0))))
         operands.append(corr_p)
 
-    kw = dict(n=n, n_pad=n_pad, h=h, m_block=m_block, sign=sign,
-              k_steps=k_steps, mode=mode, acc_dtype=acc_dtype,
-              step_impl=step_impl, with_offset=with_offset)
+    kw = dict(n=n, n_pad=n_pad, h=h, m_block=m_block, k_steps=k_steps,
+              mode=mode, acc_dtype=acc_dtype, step_impl=step_impl,
+              with_offset=with_offset)
     if stream_impl == "grid":
         kernel = functools.partial(_stream_grid_kernel, **kw)
         grid = (b, r_blocks, k_steps)
@@ -585,7 +642,7 @@ def _pallas_stream_call(g: jnp.ndarray, *, sign: int, mode: str,
                    pltpu.SemaphoreType.DMA((2,))]
         cparams = None if interpret else _COMPILER_PARAMS_2D
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -597,6 +654,7 @@ def _pallas_stream_call(g: jnp.ndarray, *, sign: int, mode: str,
         interpret=interpret,
         name=f"sfdprt_stream_{mode}",
     )(*operands)
+    return _mirror_directions(out, n) if sign > 0 else out
 
 
 @functools.partial(jax.jit,

@@ -43,12 +43,15 @@ def _warn_off_table(n: int, table: dict, warned: set, kind: str) -> None:
             stacklevel=3)
 
 # N: (strip_rows H, m_block M).  M multiples of 8 keep int32 sublane
-# tiling aligned off the interpret path.  CPU-interpret measurements
-# (N=251, int32): H=N (single strip, no alignment pass) with moderate M
-# wins -- {(251,32): 13.7ms, (251,64): 14.5ms, (64,64): 21.9ms,
-# (32,32): 16.9ms} vs horner 25.7ms; on real TPUs H instead bounds the
-# VMEM-resident strip (H*N_pad*4B), which every pinned H below respects
-# by a wide margin against the ~16 MB/core budget.
+# tiling aligned off the interpret path (and the compiled step's rotate
+# needs each m-block's first direction to be one).  N=251 is measured on
+# a TPU v5e with the strided-rotate Horner step, B=256 uint8 images, one
+# compiled forward / inverse, H=N: M=32 103.0 / 102.4 ms, M=64 63.1 /
+# 62.9 ms, M=128 44.1 / 44.8 ms, M=256 33.0 / 32.8 ms -- one m-block
+# holds all 252 direction rows, and the per-cycle cost grows less than
+# the rows it carries.  On real TPUs H bounds the VMEM-resident strip
+# (H*N_pad*4B), which every pinned H below respects by a wide margin
+# against the ~16 MB/core budget.
 PALLAS_TUNE = {
     2: (2, 8),
     3: (3, 8),
@@ -60,7 +63,7 @@ PALLAS_TUNE = {
     31: (31, 8),
     61: (61, 16),
     127: (127, 16),
-    251: (251, 32),
+    251: (251, 256),
     509: (256, 32),
     1021: (256, 64),
     # giant-N rows (the streamed-strip kernels): H=256 keeps one strip +

@@ -2,9 +2,9 @@
 
 Every other test runs the kernels in interpret mode on the CPU, which
 takes the interpret lowerings and skips Mosaic's tiling, layout and
-VMEM checks.  These tests compile the chip's own lowerings (ladder
-steps, the DMA strip stream, the projection pipeline, the per-shard
-strip kernel) at real sizes for a *described* ``v5e:2x2`` topology:
+VMEM checks.  These tests compile the chip's own lowerings (the strided
+rotate Horner step, the DMA strip stream, the projection pipeline, the
+per-shard strip kernel) at real sizes for a *described* ``v5e:2x2`` topology:
 nothing runs, but the chip's compiler refuses here what it would refuse
 on the chip.  Each compile asserts a Mosaic kernel (``tpu_custom_call``)
 is in the program under its stable name (the ``name=`` of its
@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import spans
 from repro.core.distributed import dprt_sharded_pallas
 from repro.kernels.ops import (dprt_pallas, idprt_pallas,
                                projection_pipeline_pallas)
@@ -56,11 +57,20 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *avals, name):
+def _step_rolls() -> int:
+    return spans.snapshot()["counters"].get("sfdprt_step_roll", 0)
+
+
+def _compile(fn, *avals, name, step_rolls=None):
+    """Compile ``fn`` for the described chip; ``step_rolls`` is how many
+    kernel bodies must take the strided rotate Horner step."""
+    before = _step_rolls()
     compiled = jax.jit(fn).lower(*avals).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel"
     assert name in text, f"no kernel named {name}"
+    if step_rolls is not None:
+        assert _step_rolls() - before == step_rolls
     print(compiled.memory_analysis())
     return compiled
 
@@ -71,12 +81,12 @@ def test_fused_kernels_compile_n251_b16(one_chip, direction):
     if direction == "forward":
         aval = jax.ShapeDtypeStruct((16, n, n), jnp.int32, sharding=one_chip)
         _compile(lambda f: dprt_pallas(f, interpret=False), aval,
-                 name="sfdprt_forward")
+                 name="sfdprt_forward", step_rolls=1)
     else:
         aval = jax.ShapeDtypeStruct((16, n + 1, n), jnp.int32,
                                     sharding=one_chip)
         _compile(lambda r: idprt_pallas(r, interpret=False), aval,
-                 name="sfdprt_inverse")
+                 name="sfdprt_inverse", step_rolls=1)
 
 
 @pytest.mark.parametrize("direction", ["forward", "inverse"])
@@ -85,13 +95,13 @@ def test_dma_stream_compiles_n2053(one_chip, direction):
     if direction == "forward":
         aval = jax.ShapeDtypeStruct((1, n, n), jnp.int32, sharding=one_chip)
         _compile(lambda f: dprt_pallas(f, stream_rows=rows, interpret=False),
-                 aval, name="sfdprt_stream_forward")
+                 aval, name="sfdprt_stream_forward", step_rolls=1)
     else:
         aval = jax.ShapeDtypeStruct((1, n + 1, n), jnp.int32,
                                     sharding=one_chip)
         _compile(lambda r: idprt_pallas(r, stream_rows=rows,
                                         interpret=False), aval,
-                 name="sfdprt_stream_inverse")
+                 name="sfdprt_stream_inverse", step_rolls=1)
 
 
 @pytest.mark.parametrize("n", [61, 251])
@@ -121,6 +131,6 @@ def test_sharded_strip_kernel_compiles_on_2x2(topo, monkeypatch):
                                 sharding=NamedSharding(mesh,
                                                        P("data", None, None)))
     compiled = _compile(lambda f: dprt_sharded_pallas(f, mesh), aval,
-                        name="sfdprt_forward")
+                        name="sfdprt_forward", step_rolls=1)
     assert "reduce-scatter" in compiled.as_text() or \
         "all-reduce" in compiled.as_text()
