@@ -913,6 +913,13 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
                                             and operand_form == "proj")) \
         else None
     out_ref, aux_ref, rc_ref = refs
+    # one count per body traced, credited to the executable being built:
+    # which roll step it took, and whether every grid step re-runs the
+    # conv operand's forward
+    if step_impl == "ladder":
+        spans.count("sfdprt_pipeline_ladder")
+    if g_ref is not None:
+        spans.count("sfdprt_pipeline_operand_fwd")
 
     mb = pl.program_id(1)
     zero = jnp.zeros((), acc_dtype)

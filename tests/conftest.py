@@ -1,4 +1,6 @@
+import functools
 import importlib.util
+import json
 import os
 import subprocess
 import sys
@@ -56,3 +58,38 @@ def run_subprocess(code: str, devices: int = 8, timeout: int = 600,
 @pytest.fixture
 def subproc():
     return run_subprocess
+
+
+@pytest.fixture
+def conv251_kernel():
+    """The 5x5 integer kernel of the ``conv251_u8`` benchmark
+    configuration, as uint8."""
+    import numpy as np
+    path = os.path.join(REPO, "bench", "configs", "conv251_u8.json")
+    with open(path) as fh:
+        return np.asarray(json.load(fh)["conv_kernel"], np.uint8)
+
+
+@pytest.fixture
+def pipeline_step(monkeypatch):
+    """``pipeline_step(step_impl)``: drop every cached plan, executable
+    and trace of the fused pipeline kernel, so the next build traces its
+    body anew -- with ``step_impl`` forced on every call when given
+    (``None``: the kernel's own default).  The caches are dropped again
+    after the test."""
+    from repro import radon
+    from repro.kernels import ops, sfdprt
+
+    def clear():
+        radon.aot_cache_clear()
+        radon.plan_cache_clear()
+        sfdprt.pipeline_pallas_raw.clear_cache()
+
+    def fresh(step_impl=None):
+        clear()
+        raw = sfdprt.pipeline_pallas_raw
+        if step_impl is not None:
+            raw = functools.partial(raw, step_impl=step_impl)
+        monkeypatch.setattr(ops, "pipeline_pallas_raw", raw)
+    yield fresh
+    clear()             # no later test reuses a trace of a forced step

@@ -401,6 +401,29 @@ def test_pipeline_trace_counting_and_retrace_guard():
             C.circ_conv2d_dprt(f + 1, g)
 
 
+@pytest.mark.parametrize("step_impl", [None, "ladder"])
+@pytest.mark.parametrize("n", [31, 61])
+def test_conv2d_u8_stack_matches_oracle(n, step_impl, conv251_kernel,
+                                        pipeline_step):
+    """The 2-D filtering deployment's entry, ``radon.Conv2D`` of uint8
+    stacks by its 5x5 kernel, compiled through the plan to the fused
+    pipeline kernel: bit for bit the numpy oracle, on the interpret
+    default step and on the chip's ``"ladder"`` step."""
+    pipeline_step(step_impl)
+    rng = np.random.default_rng(n)
+    x = rng.integers(0, 256, (4, n, n), dtype=np.uint8)
+    exe = radon.Conv2D((4, n, n), jnp.asarray(conv251_kernel),
+                       jnp.uint8).compile()
+    out = np.asarray(exe(jnp.asarray(x)))
+    k = conv251_kernel.shape[0]
+    g = jnp.asarray(np.pad(conv251_kernel, ((0, n - k), (0, n - k))),
+                    jnp.int32)
+    for i in range(4):
+        np.testing.assert_array_equal(
+            out[i], np.asarray(C.circ_conv2d_direct(
+                jnp.asarray(x[i], jnp.int32), g)))
+
+
 def test_pipeline_ladder_step_impl_matches_permute():
     """The rotate+select ladder datapath (the Mosaic/TPU lowering) must
     produce the same bits as the interpret-default permute lowering."""

@@ -208,3 +208,30 @@ def test_router_healthz_ends_with_set_up_spans():
     text = router.healthz()
     assert "[healthz] spans" in text
     assert "[healthz]   built radon.compile " in text
+
+
+@pytest.mark.parametrize("step_impl", [None, "ladder"])
+def test_conv2d_compile_credits_pipeline_counters(step_impl, conv251_kernel,
+                                                  pipeline_step):
+    """One ``Conv2D`` compile credits the fused pipeline kernel's
+    counters to its ``radon.compile``: the in-kernel forward of the
+    conv operand always (the kernel embeds as an image operand), the
+    ladder step when the body took it; both show on its ``built``
+    line."""
+    pipeline_step(step_impl)
+    t0 = time.perf_counter_ns()
+    radon.Conv2D((2, 13, 13), jnp.asarray(conv251_kernel),
+                 jnp.uint8).compile()
+    compiles = [r for r in _since(t0) if r["name"] == "radon.compile"]
+    assert len(compiles) == 1
+    assert compiles[0]["attrs"]["kind"] == "conv2d"
+    credits = compiles[0]["credits"]
+    assert credits.get("sfdprt_pipeline_operand_fwd", 0) >= 1
+    if step_impl == "ladder":
+        assert credits.get("sfdprt_pipeline_ladder", 0) >= 1
+    else:
+        assert "sfdprt_pipeline_ladder" not in credits
+    built = [ln for ln in healthz.span_lines()
+             if "built radon.compile" in ln and "kind=conv2d" in ln]
+    assert "sfdprt_pipeline_operand_fwd=" in built[-1]
+    assert ("sfdprt_pipeline_ladder=" in built[-1]) == (step_impl is not None)
