@@ -261,7 +261,7 @@ def pipeline_tail_pallas(rows, op: str = "conv", operand=None, *,
                          lane_batch: Optional[int] = None,
                          interpret: Optional[bool] = None):
     """Shard-local pipeline tail: already-assembled projection rows in,
-    per-direction epilogue + inverse ladder out (correction deferred).
+    per-direction epilogue + inverse skew sum out (correction deferred).
 
     ``rows``: (dirs_local, N) or (B, dirs_local, N) -- this device's
     shard of direction rows, first global direction ``row_offset``

@@ -244,7 +244,7 @@ def _mirror_directions(out: jnp.ndarray, n: int) -> jnp.ndarray:
 
 
 def _resolve_step(step_impl: str | None, interpret: bool) -> str:
-    """The strip kernels' per-cycle step: ``"roll"`` compiled,
+    """The kernels' per-cycle Horner step: ``"roll"`` compiled,
     ``"permute"`` interpreted, unless the caller names one."""
     if step_impl is None:
         return "permute" if interpret else "roll"
@@ -781,9 +781,21 @@ isfdprt_core = functools.partial(skew_sum_pallas_raw, sign=-1)
 # applies the per-direction epilogue IN REGISTERS -- a Horner-style 1-D
 # circular convolution against the operand's projections ("conv"), or a
 # pointwise projection-domain multiply ("mul") -- and immediately feeds the
-# block's direction rows through the inverse skew-sum ladder onto the full
-# output image.  The (N+1, N) projections never exist outside VMEM/registers;
+# block's direction rows through the inverse skew sum onto the full output
+# image.  The (N+1, N) projections never exist outside VMEM/registers;
 # MEM_OUT is only ever the final (N, N) image.
+#
+# **Horner step.**  Compiled (``step_impl="roll"``), both Horner stages
+# take the strip kernels' step (:func:`_strip_block_partial`): the
+# accumulator is a periodic extension on ``2*n_pad`` lanes, and each cycle
+# is ONE strided lane rotate whose amount is linear in the sublane with a
+# base that is a multiple of 8 (:func:`_roll_horner`).  The forward
+# stage's sublane r rolls by ``m0 + r`` -- the CRS direction -- and reads
+# the image rows in ``<-j>_N`` order, which makes each CRS row the forward
+# direction of the same index; the inverse stage's output row ``i0 + r``
+# rolls by ``i0 + r``.  The once-per-block eq. (7) alignment, which holds
+# the traced direction offset, stays on :func:`apply_roll_ladder`.  In
+# interpret mode (``"permute"``) every roll is one hoisted gather.
 #
 # The -S + R'(N, i) correction and exact /N divide need two *global* rows of
 # the convolved projections (row 0 for S, row N for the correction column);
@@ -792,16 +804,16 @@ isfdprt_core = functools.partial(skew_sum_pallas_raw, sign=-1)
 # in-kernel -- or leaves it to the caller (``defer=True``, the mesh-sharded
 # path, where the division must wait for the cross-device ``psum``).
 #
-# **Batch-in-lanes.**  A batched stack packs ``lane_batch`` images side by
-# side along the lane axis (segment s owns lanes [s*n_pad, (s+1)*n_pad));
-# every roll/gather/select then acts per segment, so transforming LB images
-# costs the same op count as one image with LB-times-wider tiles -- the
-# layout that keeps the CPU-interpret path from paying per-image dispatch
-# overhead.  On TPU, ``lane_batch=1`` recovers the per-image grid.
+# **Batch-in-lanes** (interpret only).  A batched stack packs
+# ``lane_batch`` images side by side along the lane axis (segment s owns
+# lanes [s*n_pad, (s+1)*n_pad)); every gather/select then acts per
+# segment, so transforming LB images costs the same op count as one image
+# with LB-times-wider tiles.  The compiled step carries one image a grid
+# step (``lane_batch=1``).
 #
 # **Tail mode** (``source="proj"``).  The input rows are already-assembled
 # projection rows (a shard of directions, first global direction
-# ``row_offset``); the kernel applies the epilogue and the inverse ladder
+# ``row_offset``); the kernel applies the epilogue and the inverse skew sum
 # for those directions only.  This is the second (per-shard) launch of the
 # mesh-distributed pipeline: forward partials are psum_scatter'd over
 # directions between the two launches -- the single collective between
@@ -825,39 +837,57 @@ def _seg_perm(amt, n: int, n_pad: int, lb: int, rows_out: int) -> jnp.ndarray:
         rows_out, lb * n_pad)
 
 
-def _seg_roller(amt, n: int, n_pad: int, lb: int, rows_out: int,
-                step_impl: str):
-    """The hoisted per-step roll for a wide tile: a closure applying
-    out[r, s, d] = acc[r, s, <d + amt[r]>_n].  ``"permute"`` materializes
-    ONE gather index (interpret/CPU); ``"ladder"`` uses the binary
-    rotate+select ladder per segment (static lane slices -- Mosaic)."""
-    if step_impl == "permute":
-        idx = _seg_perm(amt, n, n_pad, lb, rows_out)
+def _seg_roller(amt, n: int, n_pad: int, lb: int, rows_out: int):
+    """The hoisted per-step roll of the interpret (``"permute"``) step: a
+    closure applying out[r, s, d] = acc[r, s, <d + amt[r]>_n] to a wide
+    tile with ONE gather."""
+    idx = _seg_perm(amt, n, n_pad, lb, rows_out)
 
-        def roll(acc):
-            return jnp.take_along_axis(acc, idx, axis=1)
-    else:
-        masks = [m[:, :, None] for m in ladder_select_masks(amt, n)]
-
-        def roll(acc):
-            a3 = acc.reshape(rows_out, lb, n_pad)
-            for b, sel in enumerate(masks):
-                s = 1 << b
-                rolled = jnp.concatenate(
-                    [a3[:, :, s:n], a3[:, :, :s], a3[:, :, n:]], axis=2)
-                a3 = jnp.where(sel, rolled, a3)
-            return a3.reshape(rows_out, lb * n_pad)
+    def roll(acc):
+        return jnp.take_along_axis(acc, idx, axis=1)
     return roll
 
 
+def _roll_horner(read, cycles: int, rows_out: int, base, n: int,
+                 n_pad: int, acc_dtype) -> jnp.ndarray:
+    """The compiled Horner loop of the pipeline's forward and inverse
+    stages: ``cycles`` cycles of ``T[r] <- roll(T[r], base + r) +
+    read(c)``, the roll at logical width N in the ``jnp.roll`` sense
+    (lane d reads ``T[r, <d - base - r>_N]``).
+
+    As in :func:`_strip_block_partial`, T lives on lanes
+    ``[n_pad, n_pad + N)`` of a ``2*n_pad`` lane tile with a copy on the
+    N lanes below, so the roll is ONE native strided lane rotate; after
+    the row add (an aligned concat) a static rotate and a select rebuild
+    the copy.  ``base`` (static or traced) must be a multiple of 8: the
+    v5e rotates each vreg's 8 sublanes exactly only while none moves
+    past one vreg width.  ``read(c)`` is a (1, n_pad) row.  Returns the
+    (rows_out, n_pad) accumulator; its lanes >= N are leftovers that the
+    caller masks, as are its rows whose amount reaches N.
+    """
+    ext_w = 2 * n_pad
+    upper = jax.lax.broadcasted_iota(jnp.int32, (rows_out, ext_w), 1) >= n_pad
+    below = jnp.zeros((1, n_pad), acc_dtype)
+
+    def body(c, ext):
+        rot = pltpu.roll(ext, base, 1, stride=1, stride_axis=0)
+        rot = rot + jnp.concatenate([below, read(c)], axis=1)
+        return jnp.where(upper, rot, pltpu.roll(rot, ext_w - n, 1))
+
+    ext = jax.lax.fori_loop(0, cycles, body,
+                            jnp.zeros((rows_out, ext_w), acc_dtype))
+    return ext[:, n_pad:]
+
+
 def _seg_roll_static(acc3: jnp.ndarray, k: int, n: int) -> jnp.ndarray:
-    """Rotate every segment of a (rows, lb, n_pad) tile right by the
-    *static* amount k at logical width n (zero tails carried through)."""
+    """Rotate every segment of a (rows, lb, n_pad) -- or, one image, a
+    (rows, n_pad) -- tile right by the *static* amount k at logical
+    width n along its last axis (zero tails carried through)."""
     k %= n
     if k == 0:
         return acc3
-    parts = [acc3[:, :, n - k:n], acc3[:, :, :n - k], acc3[:, :, n:]]
-    return jnp.concatenate([p for p in parts if p.shape[2]], axis=2)
+    parts = [acc3[..., n - k:n], acc3[..., :n - k], acc3[..., n:]]
+    return jnp.concatenate([p for p in parts if p.shape[-1]], axis=-1)
 
 
 def _conv_epilogue(rf: jnp.ndarray, rg3: jnp.ndarray, n: int, n_pad: int,
@@ -875,14 +905,17 @@ def _conv_epilogue(rf: jnp.ndarray, rg3: jnp.ndarray, n: int, n_pad: int,
     """
     m_block = rf.shape[0]
     k = max(1, min(group, n - 1))
-    rf3 = rf.reshape(m_block, lb, n_pad)
-    rgs = [rg3]
+    # one image a tile (the compiled step): stay 2-D, since a (rows, 1,
+    # lanes) view pads each row to a whole 8-sublane tile
+    seg = (m_block, lb, n_pad) if lb > 1 else (m_block, n_pad)
+    rf3 = rf.reshape(seg)
+    rgs = [rg3.reshape(seg) if lb == 1 else rg3]
     for _ in range(1, k):
         rgs.append(_seg_roll_static(rgs[-1], 1, n))
     nk = math.ceil(n / k)
     width = max(n_pad, nk * k)
     if width > n_pad:         # taps beyond the lane pad: zero (rf tail is 0)
-        rf3 = jnp.pad(rf3, ((0, 0), (0, 0), (0, width - n_pad)))
+        rf3 = jnp.pad(rf3, [(0, 0)] * (rf3.ndim - 1) + [(0, width - n_pad)])
     # cycle j consumes taps [t0, t0 + K) with t0 = (nk - 1 - j) * K: start
     # rotated left by the first t0, then rotate right by K per cycle
     taps = _seg_roll_static(rf3, width - (nk - 1) * k, width)
@@ -891,10 +924,10 @@ def _conv_epilogue(rf: jnp.ndarray, rg3: jnp.ndarray, n: int, n_pad: int,
         acc, taps = carry
         acc = _seg_roll_static(acc, k, n)
         for u in range(k):
-            acc = acc + taps[:, :, u:u + 1] * rgs[u]
+            acc = acc + taps[..., u:u + 1] * rgs[u]
         return acc, _seg_roll_static(taps, k, width)
 
-    acc = jnp.zeros((m_block, lb, n_pad), acc_dtype)
+    acc = jnp.zeros(seg, acc_dtype)
     acc, _ = jax.lax.fori_loop(0, nk, body, (acc, taps))
     return acc.reshape(m_block, lb * n_pad)
 
@@ -916,8 +949,8 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
     # one count per body traced, credited to the executable being built:
     # which roll step it took, and whether every grid step re-runs the
     # conv operand's forward
-    if step_impl == "ladder":
-        spans.count("sfdprt_pipeline_ladder")
+    if step_impl == "roll":
+        spans.count("sfdprt_pipeline_step_roll")
     if g_ref is not None:
         spans.count("sfdprt_pipeline_operand_fwd")
 
@@ -933,10 +966,26 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
     valid_fwd = grow < n
     m_vec = jnp.where(valid_fwd, grow, 0)
     last = mb == mb_total - 1
+    # lanes >= N: zero tails under "permute", leftovers under "roll"
+    # (which runs one image, lb == 1)
+    in_lanes = (jax.lax.broadcasted_iota(jnp.int32, (1, wide), 1) < n
+                if step_impl == "roll" else True)
 
     # ---- forward stage: whole-rows Horner per direction block ------------
     def fwd_of(x_ref):
-        roll = _seg_roller(m_vec, n, n_pad, lb, m_block, step_impl)
+        if step_impl == "roll":
+            # the CRS skew sum of the rows taken in <-j>_N order,
+            # f(<-j>, .), is the forward skew sum of f: cycle c adds row
+            # <c + 1>_N, which the remaining cycles roll by
+            # (rows - 1 - c) * m = -(c + 1) * m
+            def read(c):
+                j = jnp.where(c + 1 == rows, 0, c + 1)
+                return x_ref[0, j, :][None, :].astype(acc_dtype)
+
+            acc = _roll_horner(read, rows, m_block, dir0, n, n_pad,
+                               acc_dtype)
+            return jnp.where(valid_fwd & in_lanes, acc, zero)
+        roll = _seg_roller(m_vec, n, n_pad, lb, m_block)
 
         def body(i, acc):
             row = x_ref[0, rows - 1 - i, :]
@@ -1006,33 +1055,43 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
         aux_ref[0, :2] = aux_ref[0, :2] + aux
 
     # ---- inverse stage: this block's directions onto ALL image rows ------
-    # The output rows are processed in cache-sized sub-blocks (the same
-    # tile height the dedicated inverse kernel tunes to): one (IB, wide)
-    # accumulator + its gather index stay resident per sub-block instead
-    # of a single (nr_pad, wide) mega-tile thrashing L2.
+    # The output rows are processed in sub-blocks of 256 (a multiple of
+    # 8, so each sub-block's first row is a valid base of the strided
+    # rotate): one (IB, wide) accumulator stays resident per sub-block
+    # instead of a single (nr_pad, wide) mega-tile at giant N.
     # staged in VMEM scratch: the Horner below reads one direction row
     # per cycle at a traced index, which Mosaic lowers only from a ref
     rc_ref[...] = jnp.where(valid_fwd, rc, zero)
-    ib_rows = min(64, nr_pad)
+    ib_rows = min(256, nr_pad)
     zs = []
     for i0 in range(0, nr_pad, ib_rows):
         rows_ib = min(ib_rows, nr_pad - i0)
         i_iota = i0 + jax.lax.broadcasted_iota(jnp.int32, (rows_ib, 1), 0)
         i_valid = i_iota < n
         i_vec = jnp.where(i_valid, i_iota, 0)
-        neg_i = jnp.where(i_vec == 0, 0, n - i_vec)
-        roll_inv = _seg_roller(neg_i, n, n_pad, lb, rows_ib, step_impl)
 
-        def ibody(t, acc):
-            return roll_inv(acc) + rc_ref[pl.ds(m_block - 1 - t, 1), :]
+        def read(t):
+            return rc_ref[pl.ds(m_block - 1 - t, 1), :]
 
-        z = jax.lax.fori_loop(0, m_block, ibody,
-                              jnp.zeros((rows_ib, wide), acc_dtype))
+        # Horner over the block's directions: output row i rolls by i a
+        # cycle (z[i, d] gathers rc[k, <d - i*k>_N])
+        if step_impl == "roll":
+            z = _roll_horner(read, m_block, rows_ib, i0, n, n_pad,
+                             acc_dtype)
+        else:
+            neg_i = jnp.where(i_vec == 0, 0, n - i_vec)
+            roll_inv = _seg_roller(neg_i, n, n_pad, lb, rows_ib)
+            z = jax.lax.fori_loop(
+                0, m_block, lambda t, acc: roll_inv(acc) + read(t),
+                jnp.zeros((rows_ib, wide), acc_dtype))
         # alignment: the Horner above assumed the block's first direction
         # is 0; roll each output row i by <-i * dir0>_n (eq. 7, m -> i)
         align_amt = jnp.mod(-i_vec * (dir0 % n), n)
-        z = _seg_roller(align_amt, n, n_pad, lb, rows_ib, step_impl)(z)
-        zs.append(jnp.where(i_valid, z, zero))
+        if step_impl == "roll":
+            z = apply_roll_ladder(z, ladder_select_masks(align_amt, n), n)
+        else:
+            z = _seg_roller(align_amt, n, n_pad, lb, rows_ib)(z)
+        zs.append(jnp.where(i_valid & in_lanes, z, zero))
     z = jnp.concatenate(zs, axis=0) if len(zs) > 1 else zs[0]
 
     @pl.when(mb == 0)
@@ -1106,6 +1165,11 @@ def pipeline_pallas_raw(f: jnp.ndarray, operand: jnp.ndarray | None = None,
     and N for the deferred -S + R'(N, i) correction.  Callers slice to
     (…, N, N).  ``n_rows`` is the transform size N when ``source="proj"``
     rows don't imply it.
+
+    ``step_impl`` (default: ``"permute"`` in interpret mode, ``"roll"``
+    compiled) picks the Horner step.  ``"roll"`` runs on the chip's
+    layout in interpret mode too: lanes padded to a 128-multiple, one
+    image a grid step (``lane_batch=1``).
     """
     if op not in PIPELINE_OPS:
         raise ValueError(f"pipeline op must be one of {PIPELINE_OPS}: {op!r}")
@@ -1114,9 +1178,11 @@ def pipeline_pallas_raw(f: jnp.ndarray, operand: jnp.ndarray | None = None,
         n = f.shape[-1] if n_rows is None else n_rows
     acc_dtype = f.dtype
     lb = max(1, min(int(lane_batch), b))
-    if step_impl is None:
-        step_impl = "permute" if interpret else "ladder"
-    lane_pad = not interpret
+    step_impl = _resolve_step(step_impl, interpret)
+    if step_impl == "roll" and lb > 1:
+        raise ValueError(f"the compiled pipeline step takes one image a "
+                         f"grid step: lane_batch must be 1, got {lb}")
+    lane_pad = not interpret or step_impl == "roll"
     n_pad = ((n + LANE - 1) // LANE) * LANE if lane_pad else n
     nr_pad = ((n + 7) // 8) * 8
     bg = math.ceil(b / lb)

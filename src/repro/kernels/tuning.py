@@ -150,17 +150,20 @@ def wasted_direction_rows(n: int, m_block: int, forward: bool = True) -> int:
 # whole image as ONE strip (H = N: the conv epilogue needs each
 # direction's complete projection before it can run), so its only block
 # knobs are the direction block M and the Horner conv tap group K.
-# CPU-interpret measurements at N=251 (min-of-many, 2-core host):
-# M=64/K=4 31.2 ms vs M=32/K=8 31.9, M=128+ worse (alignment tile and
-# iota setup outgrow L2); small primes are a single m-block.  On real
-# TPUs M bounds the accumulator sublanes ((M + N_pad_rows) * N_pad *
-# itemsize VMEM per step) -- re-measure on Mosaic before trusting these.
+# N=251 is measured on a TPU v5e with the strided-rotate Horner step,
+# B=64 images convolved by a 5x5 image operand, K=4: M=64 0.682,
+# M=128 0.524, M=256 0.430 ms per image -- one m-block holds all 252
+# direction rows, as in PALLAS_TUNE.  The other rows are CPU-interpret
+# picks (M=64/K=4 beat M=32/K=8 there); small primes are a single
+# m-block.  On real TPUs M bounds the accumulator sublanes ((M +
+# N_pad_rows) * N_pad * itemsize VMEM per step) -- re-measure on Mosaic
+# before trusting these.
 # Every M is a multiple of 8: Mosaic tiles a block's second-minor axis in
 # 8-row sublane groups and refuses the operand blocks otherwise.
 PIPELINE_TUNE = {
     61: (64, 4),
     127: (64, 4),
-    251: (64, 4),
+    251: (256, 4),
     509: (64, 4),
     1021: (64, 4),
     2053: (64, 4),

@@ -8,7 +8,8 @@
   m-block, never inside the Horner loop,
 * the compiled per-cycle step (one strided lane rotate on a periodically
   extended accumulator) forced in interpret mode with padded lanes,
-  bit-exact against the oracles, and its loop body's shape,
+  bit-exact against the oracles, and its loop body's shape -- in the
+  strip kernels and in the fused projection pipeline kernel,
 * masked final m-block + lane padding: no wrapped-duplicate garbage,
 * overflow-safe accumulators (int64 survives under x64),
 * conv routing through the dispatch.
@@ -288,6 +289,157 @@ def test_roll_step_loop_body_is_one_native_rotate(mode, n, h):
             cuts = np.cumsum([v.aval.shape[d] for v in b.invars])[:-1]
             assert (cuts % 128 == 0).all(), f"unaligned lane cut {cuts}"
         assert "shift_right" not in b.primitive.name
+
+
+# ---------------------------------------------------------------------------
+# the fused pipeline kernel on the compiled step
+# ---------------------------------------------------------------------------
+def _circ_conv_np(f, g):
+    """out[x, y] = sum_{a,b} g[a, b] f[<x - a>, <y - b>]: the definition,
+    summed over g's nonzero taps, in int64."""
+    out = np.zeros(f.shape, np.int64)
+    for a, b in zip(*np.nonzero(g)):
+        out += int(g[a, b]) * np.roll(f.astype(np.int64), (a, b), (0, 1))
+    return out
+
+
+def _pipeline_want(f, op, g, w):
+    """The oracle of one image: exact circular convolution by ``g``, or
+    the inverse of the forward times ``w`` with the kernel's floored /N
+    (the DPRT oracle's inverse of N * r is the numerator itself)."""
+    n = f.shape[-1]
+    if op == "conv":
+        return _circ_conv_np(f, g)
+    r = D.dprt_oracle_np(f)
+    if op == "mul":
+        r = r * w
+    return D.idprt_oracle_np(n * r) // n
+
+
+def _pipeline_roll(f, operand, op, form, source, mb, row_offset=None):
+    from repro.kernels.sfdprt import pipeline_pallas_raw
+    out, aux = pipeline_pallas_raw(
+        jnp.asarray(f), None if operand is None else jnp.asarray(operand),
+        op=op, operand_form=form, source=source, m_block=mb,
+        interpret=True, step_impl="roll", row_offset=row_offset,
+        n_rows=f.shape[-1])
+    out, aux = np.asarray(out, np.int64), np.asarray(aux, np.int64)
+    assert out.shape[-1] == 128 * -(-f.shape[-1] // 128)
+    return out, aux
+
+
+# (N, m_block): no m_block divides N + 1; N=251 runs the chip's tuned M
+PIPE_ROLL_CASES = [(5, 4), (13, 8), (61, 24), (251, 256)]
+# (op, operand_form, source): source "proj" is the mesh tail, run as
+# three direction shards with nonzero traced offsets
+PIPE_ROLL_OPS = [("conv", "image", "image"), ("conv", "proj", "image"),
+                 ("mul", "proj", "image"), ("none", "proj", "image"),
+                 ("conv", "proj", "proj"), ("mul", "proj", "proj")]
+
+
+@pytest.mark.parametrize("n,mb", PIPE_ROLL_CASES)
+@pytest.mark.parametrize("op,form,source", PIPE_ROLL_OPS)
+def test_pipeline_roll_step_matches_oracles(op, form, source, n, mb):
+    """The fused pipeline kernel on the compiled step (strided lane
+    rotate Horner, 128-padded lanes) is bit-exact against the numpy
+    oracles: conv by an image or by its projections, a pointwise
+    multiply by per-image weights, the round trip, and the tail over
+    direction shards whose partials sum to the whole."""
+    rng = np.random.default_rng(n * 7 + mb)
+    f = rand_img(n, seed=n + mb, shape=(2, n, n))
+    g = np.zeros((n, n), np.int32)
+    k = min(n, 5)
+    g[:k, :k] = rng.integers(0, 9, (k, k))
+    w = rng.integers(0, 9, (2, n + 1, n)).astype(np.int32)
+    if op == "conv":
+        operand = (g if form == "image" else D.dprt_oracle_np(g))[None]
+    else:
+        operand = w if op == "mul" else None
+    if source == "image":
+        out = _pipeline_roll(f, operand, op, form, source, mb)[0]
+        assert (out[:, n:] == 0).all() and (out[:, :, n:] == 0).all()
+        got = out[:, :n, :n]
+    else:
+        if op == "mul":          # the tail shares one operand
+            operand, w = w[:1], np.broadcast_to(w[:1], w.shape)
+        proj = np.stack([D.dprt_oracle_np(x) for x in f])
+        h = -(-(n + 1) // 3)
+        z = aux = 0
+        for off in range(0, n + 1, h):
+            zi, ai = _pipeline_roll(
+                proj[:, off:off + h].astype(np.int32),
+                operand.astype(np.int32), op, form, source, mb,
+                row_offset=off)
+            z, aux = z + zi, aux + ai
+        s = aux[:, 0, :n].sum(axis=1)[:, None, None]
+        got = (z[:, :n, :n] - s + aux[:, 1, :n][:, :, None]) // n
+    for i in range(2):
+        np.testing.assert_array_equal(
+            got[i], _pipeline_want(f[i], op, g, w[i]))
+
+
+@pytest.mark.parametrize("n", [13, 251])
+@pytest.mark.parametrize("op,form,source", [("conv", "image", "image"),
+                                            ("none", "proj", "proj")])
+def test_pipeline_roll_loop_bodies_are_one_native_rotate(op, form, source,
+                                                         n):
+    """Every Horner loop of the pipeline on the compiled step -- the
+    image's and the conv operand's forward, and each inverse sub-block --
+    holds exactly one strided and one static rotate, no concatenate of
+    lane slices at offsets off the 128-lane tile, and no mask
+    derivation."""
+    from repro.kernels.sfdprt import pipeline_pallas_raw
+    mb = 64 if n == 251 else 8
+    rows = n if source == "image" else n + 1
+    x = jnp.zeros((1, rows, n), jnp.int32)
+    g = jnp.zeros((1, n, n), jnp.int32) if op == "conv" else None
+    closed = jax.make_jaxpr(lambda a: pipeline_pallas_raw(
+        a, g, op=op, operand_form=form, source=source, m_block=mb,
+        interpret=True, step_impl="roll",
+        row_offset=None if source == "image" else 8, n_rows=n))(x)
+    loops = [e for e in _all_eqns(closed.jaxpr)
+             if e.primitive.name in ("scan", "while")
+             and any(_strided(b) for s in _sub_jaxprs(e)
+                     for b in _all_eqns(s))
+             and not any(b.primitive.name in ("scan", "while")
+                         for s in _sub_jaxprs(e) for b in _all_eqns(s))]
+    forwards = 2 if op == "conv" else 0
+    assert len(loops) == forwards + -(-n // 256), "a loop per Horner"
+    for loop in loops:
+        body = [b for s in _sub_jaxprs(loop) for b in _all_eqns(s)]
+        rolls = [b for b in body if b.primitive.name == "roll"]
+        assert sum(map(_strided, rolls)) == 1
+        assert len(rolls) == 2, "one strided and one static rotate"
+        for b in body:
+            if b.primitive.name == "concatenate":
+                d = b.params["dimension"]
+                cuts = np.cumsum([v.aval.shape[d] for v in b.invars])[:-1]
+                assert (cuts % 128 == 0).all(), f"unaligned lane cut {cuts}"
+            assert "shift_right" not in b.primitive.name
+
+
+def test_pipeline_step_roll_counted_and_refusals():
+    """``sfdprt_pipeline_step_roll`` counts each pipeline body traced
+    with the compiled step, and only those; the retired ``"ladder"``
+    step and a lane-batched compiled step are refused."""
+    from repro.core import spans
+    from repro.kernels.sfdprt import pipeline_pallas_raw
+
+    def rolls():
+        return spans.snapshot()["counters"].get(
+            "sfdprt_pipeline_step_roll", 0)
+
+    x = jnp.zeros((2, 7, 7), jnp.int32)
+    for impl, grows in (("roll", 1), ("permute", 0)):
+        before = rolls()
+        jax.make_jaxpr(lambda a, im=impl: pipeline_pallas_raw(
+            a, op="none", m_block=8, step_impl=im))(x)
+        assert rolls() - before == grows, impl
+    with pytest.raises(ValueError, match="step_impl"):
+        pipeline_pallas_raw(x, op="none", m_block=8, step_impl="ladder")
+    with pytest.raises(ValueError, match="lane_batch"):
+        pipeline_pallas_raw(x, op="none", m_block=8, lane_batch=2,
+                            step_impl="roll")
 
 
 # ---------------------------------------------------------------------------

@@ -401,14 +401,14 @@ def test_pipeline_trace_counting_and_retrace_guard():
             C.circ_conv2d_dprt(f + 1, g)
 
 
-@pytest.mark.parametrize("step_impl", [None, "ladder"])
+@pytest.mark.parametrize("step_impl", [None, "roll"])
 @pytest.mark.parametrize("n", [31, 61])
 def test_conv2d_u8_stack_matches_oracle(n, step_impl, conv251_kernel,
                                         pipeline_step):
     """The 2-D filtering deployment's entry, ``radon.Conv2D`` of uint8
     stacks by its 5x5 kernel, compiled through the plan to the fused
     pipeline kernel: bit for bit the numpy oracle, on the interpret
-    default step and on the chip's ``"ladder"`` step."""
+    default step and on the chip's ``"roll"`` step."""
     pipeline_step(step_impl)
     rng = np.random.default_rng(n)
     x = rng.integers(0, 256, (4, n, n), dtype=np.uint8)
@@ -422,23 +422,6 @@ def test_conv2d_u8_stack_matches_oracle(n, step_impl, conv251_kernel,
         np.testing.assert_array_equal(
             out[i], np.asarray(C.circ_conv2d_direct(
                 jnp.asarray(x[i], jnp.int32), g)))
-
-
-def test_pipeline_ladder_step_impl_matches_permute():
-    """The rotate+select ladder datapath (the Mosaic/TPU lowering) must
-    produce the same bits as the interpret-default permute lowering."""
-    from repro.kernels.sfdprt import pipeline_pallas_raw
-    rng = np.random.default_rng(15)
-    n = 13
-    fb = jnp.asarray(rng.integers(0, 30, (2, n, n)), jnp.int32)
-    g = jnp.asarray(rng.integers(0, 9, (n, n)), jnp.int32)
-    for op, operand, form in [("conv", g[None], "image"), ("none", None,
-                                                          "proj")]:
-        a, _ = pipeline_pallas_raw(fb, operand, op=op, operand_form=form,
-                                   m_block=4, group=3, step_impl="permute")
-        b, _ = pipeline_pallas_raw(fb, operand, op=op, operand_form=form,
-                                   m_block=4, group=3, step_impl="ladder")
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_fused_composite_aot_survives_plan_cache_clear():

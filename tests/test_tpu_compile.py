@@ -122,7 +122,8 @@ def test_pipeline_compiles(one_chip, op, n):
     w = jax.ShapeDtypeStruct((rows, n), jnp.int32, sharding=one_chip)
     _compile(lambda x, y: projection_pipeline_pallas(x, op, y,
                                                      interpret=False), f, w,
-             name=f"sfdprt_pipeline_{op}")
+             name=f"sfdprt_pipeline_{op}",
+             counts={"sfdprt_pipeline_step_roll": 1})
 
 
 def test_conv2d_u8_compiles_b64_n251(one_chip, conv251_kernel,
@@ -130,7 +131,7 @@ def test_conv2d_u8_compiles_b64_n251(one_chip, conv251_kernel,
     """The 2-D filtering deployment's entry at its own shape,
     ``radon.Conv2D((64, 251, 251), 5x5 kernel, uint8)``, through the
     plan to one fused pipeline kernel: its body traced once, with the
-    ladder step and the conv operand's in-kernel forward."""
+    strided-rotate step and the conv operand's in-kernel forward."""
     pipeline_step()
     # the kernel wrappers pick interpret mode from the process's backend
     # (the CPU here); steer them to the chip's lowering
@@ -139,8 +140,9 @@ def test_conv2d_u8_compiles_b64_n251(one_chip, conv251_kernel,
                       jnp.uint8)
     aval = jax.ShapeDtypeStruct(op.shape_in, jnp.uint8, sharding=one_chip)
     _compile(lambda f: op(f), aval, name="sfdprt_pipeline_conv",
-             counts={"sfdprt_pipeline_ladder": 1,
-                     "sfdprt_pipeline_operand_fwd": 1})
+             counts={"sfdprt_pipeline_step_roll": 1,
+                     "sfdprt_pipeline_operand_fwd": 1,
+                     "sfdprt_pipeline_ladder": 0})
 
 
 def test_sharded_strip_kernel_compiles_on_2x2(topo, monkeypatch):
