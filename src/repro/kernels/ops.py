@@ -12,8 +12,11 @@ The forward/inverse epilogues (R(N, d) row-sum; -S + R(N, i) correction
 plus exact divide-by-N) are fused *inside* the kernel -- see
 :mod:`.sfdprt` -- so there are no post-kernel passes here, only slicing.
 
-``interpret`` defaults to auto: Pallas interpret mode off-TPU (this
-container is CPU-only), compiled Mosaic on real TPUs.
+``interpret`` defaults to auto: Pallas interpret mode off-TPU, compiled
+Mosaic on a TPU.  It chooses only how the kernels run, not what they
+run: interpret mode executes the chip's own datapath (the strided-rotate
+Horner step, 128-padded lanes, the in-kernel DMA strip stream, one image
+a grid step), so the CPU tests check the lowering the chip compiles.
 """
 from __future__ import annotations
 
@@ -68,7 +71,7 @@ def skew_sum_pallas(g: jnp.ndarray, sign: int = 1,
     The batched form serves the plan layer's batched-native adjoint
     datapath (exact VJPs through ``method="pallas"``) as well as the
     core-mode tests.  ``stream_rows`` switches to the streamed-strip
-    kernel (VMEM scratch accumulation / double-buffered DMA; giant N).
+    kernel (double-buffered DMA; giant N).
     """
     h, mb = _resolve_blocks(g.shape[-1], strip_rows, m_block, g.dtype,
                             stream_rows)
@@ -133,9 +136,9 @@ def dprt_pallas(f: jnp.ndarray, strip_rows: Optional[int] = None,
 
     (N, N) -> (N+1, N), or batched (B, N, N) -> (B, N+1, N) in a single
     pallas_call.  Block shapes default to the :mod:`.tuning` table.
-    ``stream_rows`` streams H-row strips through ONE launch (VMEM
-    scratch accumulation; double-buffered HBM DMA off-interpret) for
-    images too large to sit whole in VMEM.
+    ``stream_rows`` streams H-row strips through ONE launch
+    (double-buffered HBM->VMEM DMA) for images too large to sit whole in
+    VMEM.
     """
     single = f.ndim == 2
     fb = f[None] if single else f
@@ -175,22 +178,10 @@ def idprt_pallas(r: jnp.ndarray, strip_rows: Optional[int] = None,
     return out[0] if single else out
 
 
-def _lane_batch_for(lane_batch=None) -> int:
-    """Batch-in-lanes width.  Packing LB images side by side along the
-    lane axis trades op count for tile width; measured on the 2-core
-    CPU-interpret host the per-image grid (LB=1) wins once the inverse
-    stage is output-row-blocked (wide tiles thrash L2), so LB=1 is the
-    default everywhere -- the knob stays for wider hosts / re-tuning."""
-    if lane_batch is not None:
-        return max(1, int(lane_batch))
-    return 1
-
-
 def projection_pipeline_pallas(f, op: str = "conv", operand=None,
                                operand_form: Optional[str] = None,
                                m_block: Optional[int] = None,
                                group: Optional[int] = None,
-                               lane_batch: Optional[int] = None,
                                interpret: Optional[bool] = None):
     """Fused projection-domain pipeline: inverse(op(forward(f))) in ONE
     ``pallas_call`` -- the projections never round-trip through HBM.
@@ -245,11 +236,9 @@ def projection_pipeline_pallas(f, op: str = "conv", operand=None,
     interp = _auto_interpret(interpret)
     mb, grp = resolve_pipeline_blocks(n, jnp.dtype(acc).itemsize,
                                       m_block, group)
-    lb = _lane_batch_for(lane_batch)
     out, _aux = pipeline_pallas_raw(fb.astype(acc), wb, op=op,
                                     operand_form=operand_form or "proj",
-                                    m_block=mb, group=grp, lane_batch=lb,
-                                    interpret=interp)
+                                    m_block=mb, group=grp, interpret=interp)
     out = out[:, :n, :n]
     return out[0] if single else out
 
@@ -258,7 +247,6 @@ def pipeline_tail_pallas(rows, op: str = "conv", operand=None, *,
                          row_offset=0, n: Optional[int] = None,
                          m_block: Optional[int] = None,
                          group: Optional[int] = None,
-                         lane_batch: Optional[int] = None,
                          interpret: Optional[bool] = None):
     """Shard-local pipeline tail: already-assembled projection rows in,
     per-direction epilogue + inverse skew sum out (correction deferred).
@@ -280,14 +268,13 @@ def pipeline_tail_pallas(rows, op: str = "conv", operand=None, *,
     mb, grp = resolve_pipeline_blocks(n, jnp.dtype(acc).itemsize,
                                       m_block, group)
     mb = min(mb, math.ceil(rb.shape[-2] / 8) * 8)
-    lb = _lane_batch_for(lane_batch)
     wb = None
     if op != "none":
         wb = operand[None] if operand.ndim == 2 else operand
         wb = wb.astype(acc)
     z, aux = pipeline_pallas_raw(rb.astype(acc), wb, op=op,
                                  operand_form="proj", source="proj",
-                                 m_block=mb, group=grp, lane_batch=lb,
+                                 m_block=mb, group=grp,
                                  interpret=interp, row_offset=row_offset,
                                  n_rows=n)
     z = z[:, :n, :n]

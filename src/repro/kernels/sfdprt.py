@@ -49,9 +49,12 @@ eq. (7) alignment roll R'(r,m,d) = U_r(<d + m*rH>) -- which is
 not linear in the sublane once reduced mod N, runs once per strip, and
 stays a ceil(log2 N)-step **binary roll-select ladder**
 (:func:`apply_roll_ladder`, masks ``(amt >> b) & 1`` from
-:func:`ladder_select_masks`).  On the interpret/CPU ``"permute"``
-lowering the permutations are materialized in index space instead and
-the alignment is ONE gather.  Nothing is re-derived on a Horner cycle.
+:func:`ladder_select_masks`).  Nothing is re-derived on a Horner cycle.
+
+**One datapath.**  Interpret mode (the CPU) runs the same lowering the
+chip compiles: ``pltpu.roll`` there has ``jnp.roll`` semantics, the
+lanes are padded the same way, and the giant-N stream is the same
+in-kernel DMA loop, so the tests check what the chip runs.
 
 **Shard-local partials.**  Every mode accepts ``rows < N`` inputs plus
 a (possibly traced) ``row_offset`` scalar operand: the mesh-distributed
@@ -59,8 +62,8 @@ backend (:mod:`repro.core.distributed`) runs this kernel per device
 over its local row super-strip, with the device's first global row
 folded into the alignment roll amount at zero extra datapath cost.
 
-**Lane padding.**  Off the interpret path the lane axis is padded to a
-multiple of 128 so Mosaic tiling is aligned; the periodic extension and
+**Lane padding.**  The lane axis is padded to a multiple of 128 so
+Mosaic tiling is aligned; the periodic extension and
 the alignment ladder (which slices at the *logical* N,
 ``[s:n] ++ [:s] ++ [n:]``) keep the wraparound at N exact, and the lane
 tail is masked back to zero once per strip.
@@ -109,6 +112,11 @@ __all__ = [
 LANE = 128  # TPU lane width; Mosaic tiles want the last axis % 128 == 0
 
 
+def _padded_lanes(n: int) -> int:
+    """The lane width of an N-wide row: N rounded up to a multiple of 128."""
+    return ((n + LANE - 1) // LANE) * LANE
+
+
 def _num_bits(n: int) -> int:
     return max(1, math.ceil(math.log2(n)))
 
@@ -152,40 +160,34 @@ def apply_roll_ladder(acc: jnp.ndarray, masks, n: int) -> jnp.ndarray:
 
 def _strip_block_partial(read_row, *, h: int, n: int, n_pad: int,
                          m_block: int, m0, m_vec, valid, offset,
-                         step_impl: str, acc_dtype):
+                         acc_dtype):
     """Aligned, masked partial CRS skew-sum of ONE H-row strip for one
     m-block: ``out[r, d] = sum_j row_j(<d - m_r*(offset + j)>_N)`` with
     ``m_r = m0 + r`` (``m_vec`` is the same, masked).
 
     This is the shared per-strip datapath of the fused (`_sfdprt_kernel`)
-    and streamed (`_stream_grid_kernel` / `_stream_dma_kernel`) kernels:
-    hoisted roll setup (per strip, not per cycle), H Horner cycles over
-    ``read_row(j)`` (j = 0 is the strip's top row), the eq. (7)
-    alignment roll for the strip's first global row ``offset`` (static
-    or traced), and the wrapped-duplicate row and lane-tail mask.
-    ``step_impl`` picks the per-cycle roll:
+    and streamed (`_stream_dma_kernel`) kernels: hoisted roll setup (per
+    strip, not per cycle), H Horner cycles over ``read_row(j)`` (j = 0
+    is the strip's top row), the eq. (7) alignment roll for the strip's
+    first global row ``offset`` (static or traced), and the
+    wrapped-duplicate row and lane-tail mask.
 
-    * ``"roll"`` (compiled) -- the loop carries the accumulator on lanes
-      ``[n_pad, n_pad + N)`` of an ``L = 2*n_pad`` lane tile whose lanes
-      ``[n_pad - N, n_pad)`` hold a second copy, so the tile is periodic,
-      ``ext[y] = acc[(y - n_pad) mod N]``, over ``(n_pad - N, n_pad + N)``.
-      Sublane ``r`` reads ``ext[y - m_r]``, which never leaves that
-      range: ONE native strided lane rotate by ``m0 + r`` (``pltpu.roll``
-      with ``stride=1`` over the sublanes).  Mosaic rotates each vreg's
-      8 sublanes exactly only while none moves past one vreg width: it
-      holds because ``m0`` is a multiple of 8 (Mosaic tiles an m-block
-      in 8 sublanes, or there is one block and ``m0 = 0``), and it is
-      why the rotate never runs backwards (a stride of ``L - 1``).
-      After the row add
-      (an aligned concat), one static rotate by ``L - N`` and one select
-      rebuild the copy for the next cycle.
-    * ``"permute"`` (interpret/CPU) -- the step and alignment
-      permutations materialized in index space once, one
-      ``take_along_axis`` per cycle (a gather is cheap there).
+    The loop carries the accumulator on lanes ``[n_pad, n_pad + N)`` of
+    an ``L = 2*n_pad`` lane tile whose lanes ``[n_pad - N, n_pad)`` hold
+    a second copy, so the tile is periodic, ``ext[y] = acc[(y - n_pad)
+    mod N]``, over ``(n_pad - N, n_pad + N)``.  Sublane ``r`` reads
+    ``ext[y - m_r]``, which never leaves that range: ONE native strided
+    lane rotate by ``m0 + r`` (``pltpu.roll`` with ``stride=1`` over the
+    sublanes).  Mosaic rotates each vreg's 8 sublanes exactly only while
+    none moves past one vreg width: it holds because ``m0`` is a
+    multiple of 8 (Mosaic tiles an m-block in 8 sublanes, or there is
+    one block and ``m0 = 0``), and it is why the rotate never runs
+    backwards (a stride of ``L - 1``).  After the row add (an aligned
+    concat), one static rotate by ``L - N`` and one select rebuild the
+    copy for the next cycle.
 
     The alignment roll ``-m*offset mod N`` is not linear in the sublane
-    once reduced, and runs once per strip: the compiled path keeps it on
-    :func:`apply_roll_ladder`.
+    once reduced, and runs once per strip on :func:`apply_roll_ladder`.
     """
     zero = jnp.zeros((), acc_dtype)
     # reduce the offset mod N before the multiply: streamed/sharded
@@ -195,42 +197,24 @@ def _strip_block_partial(read_row, *, h: int, n: int, n_pad: int,
     align_amt = jnp.mod(-m_vec * (offset % n), n)
     lane_iota = jax.lax.broadcasted_iota(jnp.int32, (m_block, n_pad), 1)
 
-    if step_impl == "permute":
-        in_tail = lane_iota >= n
-        perm = jnp.where(in_tail, lane_iota,
-                         (lane_iota + (n - m_vec)) % n)
-        align_perm = jnp.where(in_tail, lane_iota,
-                               (lane_iota + align_amt) % n)
+    spans.count("sfdprt_step_roll")
+    ext_w = 2 * n_pad
+    upper = jax.lax.broadcasted_iota(jnp.int32,
+                                     (m_block, ext_w), 1) >= n_pad
+    below = jnp.zeros((1, n_pad), acc_dtype)
+    align_sel = ladder_select_masks(align_amt, n)
 
-        def body(i, acc):
-            # T_i = f(i, .) + roll(T_{i+1}, -m): one "clock cycle"
-            acc = jnp.take_along_axis(acc, perm, axis=1)
-            return acc + read_row(h - 1 - i)[None, :].astype(acc.dtype)
+    def body(i, ext):
+        # jnp.roll semantics: sublane r's lane y reads ext[y - m0 - r]
+        rot = pltpu.roll(ext, m0, 1, stride=1, stride_axis=0)
+        row = read_row(h - 1 - i)[None, :].astype(acc_dtype)
+        rot = rot + jnp.concatenate([below, row], axis=1)
+        return jnp.where(upper, rot, pltpu.roll(rot, ext_w - n, 1))
 
-        acc = jax.lax.fori_loop(0, h, body,
-                                jnp.zeros((m_block, n_pad), acc_dtype))
-        # alignment roll: R'(r, m, d) = U_r(<d - m*rH>_n)   (eq. 7)
-        acc = jnp.take_along_axis(acc, align_perm, axis=1)
-    else:
-        spans.count("sfdprt_step_roll")
-        ext_w = 2 * n_pad
-        upper = jax.lax.broadcasted_iota(jnp.int32,
-                                         (m_block, ext_w), 1) >= n_pad
-        below = jnp.zeros((1, n_pad), acc_dtype)
-        align_sel = ladder_select_masks(align_amt, n)
-
-        def body(i, ext):
-            # jnp.roll semantics: sublane r's lane y reads ext[y - m0 - r]
-            rot = pltpu.roll(ext, m0, 1, stride=1, stride_axis=0)
-            row = read_row(h - 1 - i)[None, :].astype(acc_dtype)
-            rot = rot + jnp.concatenate([below, row], axis=1)
-            return jnp.where(upper, rot, pltpu.roll(rot, ext_w - n, 1))
-
-        ext = jax.lax.fori_loop(0, h, body,
-                                jnp.zeros((m_block, ext_w), acc_dtype))
-        # lanes >= n of the slice are left over from the rotate: zeroed
-        # below
-        acc = apply_roll_ladder(ext[:, n_pad:], align_sel, n)
+    ext = jax.lax.fori_loop(0, h, body,
+                            jnp.zeros((m_block, ext_w), acc_dtype))
+    # lanes >= n of the slice are left over from the rotate: zeroed below
+    acc = apply_roll_ladder(ext[:, n_pad:], align_sel, n)
     return jnp.where(jnp.logical_and(valid, lane_iota < n), acc, zero)
 
 
@@ -243,30 +227,17 @@ def _mirror_directions(out: jnp.ndarray, n: int) -> jnp.ndarray:
                            axis=1)
 
 
-def _resolve_step(step_impl: str | None, interpret: bool) -> str:
-    """The kernels' per-cycle Horner step: ``"roll"`` compiled,
-    ``"permute"`` interpreted, unless the caller names one."""
-    if step_impl is None:
-        return "permute" if interpret else "roll"
-    if step_impl not in ("roll", "permute"):
-        raise ValueError(f"step_impl must be 'roll' or 'permute': "
-                         f"{step_impl!r}")
-    return step_impl
-
-
 def _sfdprt_kernel(f_ref, *rest, n: int, n_pad: int, h: int, m_block: int,
                    k_steps: int, mode: str, acc_dtype,
-                   step_impl: str, with_offset: bool = False):
+                   with_offset: bool = False):
     """One (batch, m-block, strip) grid step of the fused SFDPRT.
 
     Grid is (B, MB, K) with K innermost ("arbitrary"): for a fixed
     (batch, m-block) the output block stays resident while strips
     accumulate into it -- the paper's MEM_OUT (eq. 8).
 
-    ``step_impl`` picks how each Horner cycle realizes the roll: the
-    native strided rotate on a periodically extended accumulator
-    (``"roll"``, compiled) or a hoisted index-space permutation
-    (``"permute"``, interpret) -- see :func:`_strip_block_partial`.
+    Each Horner cycle is one native strided rotate on a periodically
+    extended accumulator -- see :func:`_strip_block_partial`.
 
     ``with_offset`` threads a (1, 1) scalar operand holding the strip's
     first *global* image row (the mesh-sharded path: each device's local
@@ -290,15 +261,14 @@ def _sfdprt_kernel(f_ref, *rest, n: int, n_pad: int, h: int, m_block: int,
     m_vec = jnp.where(valid, grow, 0)
 
     # ---- hoisted ladder setup + H Horner cycles + eq. (7) alignment ------
-    # (the shared per-strip datapath; the "permute" lowering hoists the
-    # step AND alignment permutations into index space ONCE per m-block)
+    # (the shared per-strip datapath)
     offset = k * h                            # strip's first global row rH
     if with_offset:                           # shard-local: + the block's
         offset = offset + off_ref[0, 0]       # first global image row
     acc = _strip_block_partial(
         lambda j: f_ref[0, j, :], h=h, n=n, n_pad=n_pad, m_block=m_block,
         m0=mb * m_block, m_vec=m_vec, valid=valid, offset=offset,
-        step_impl=step_impl, acc_dtype=acc_dtype)
+        acc_dtype=acc_dtype)
 
     @pl.when(k == 0)
     def _init():
@@ -340,8 +310,6 @@ def _sfdprt_kernel(f_ref, *rest, n: int, n_pad: int, h: int, m_block: int,
 def _pallas_skew_call(g: jnp.ndarray, *, sign: int, mode: str,
                       strip_rows: int, m_block: int, interpret: bool,
                       corr: jnp.ndarray | None = None,
-                      lane_pad: bool | None = None,
-                      step_impl: str | None = None,
                       row_offset: jnp.ndarray | int | None = None
                       ) -> jnp.ndarray:
     """Shared fused pallas_call: g is (B, rows, N) already in the
@@ -352,24 +320,16 @@ def _pallas_skew_call(g: jnp.ndarray, *, sign: int, mode: str,
     (``sign=+1``) call reorders its direction rows after the launch
     (:func:`_mirror_directions`), one pass over the output in XLA.
 
-    ``lane_pad`` (default: pad iff compiled) rounds the lane axis up to a
-    128-multiple for Mosaic tile alignment; it is overridable so the
-    wraparound-at-logical-N path is testable in interpret mode.
-    ``step_impl`` (default: "permute" in interpret mode, "roll"
-    compiled) picks the per-cycle roll realization -- see
-    :func:`_strip_block_partial`.  ``row_offset`` (static or traced scalar)
-    is the first *global* image row of ``g``'s row block -- the
-    shard-local partial of the mesh path; it feeds the alignment ladder
-    only (core mode).
+    The lane axis is rounded up to a 128-multiple for Mosaic tile
+    alignment.  ``row_offset`` (static or traced scalar) is the first
+    *global* image row of ``g``'s row block -- the shard-local partial
+    of the mesh path; it feeds the alignment ladder only (core mode).
     """
     b, rows, n = g.shape
     acc_dtype = g.dtype
     h = max(1, min(int(strip_rows), rows))
     k_steps = math.ceil(rows / h)
-    if lane_pad is None:
-        lane_pad = not interpret
-    step_impl = _resolve_step(step_impl, interpret)
-    n_pad = ((n + LANE - 1) // LANE) * LANE if lane_pad else n
+    n_pad = _padded_lanes(n)
     out_rows = n + 1 if mode == "forward" else n
     r_blocks = math.ceil(out_rows / m_block)
 
@@ -392,7 +352,7 @@ def _pallas_skew_call(g: jnp.ndarray, *, sign: int, mode: str,
         functools.partial(_sfdprt_kernel, n=n, n_pad=n_pad, h=h,
                           m_block=m_block, k_steps=k_steps,
                           mode=mode, acc_dtype=acc_dtype,
-                          step_impl=step_impl, with_offset=with_offset),
+                          with_offset=with_offset),
         grid=(b, r_blocks, k_steps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, m_block, n_pad),
@@ -414,93 +374,24 @@ def _pallas_skew_call(g: jnp.ndarray, *, sign: int, mode: str,
 # fine while ceil(N/H) output revisits are free (they stay VMEM-resident)
 # but it leans on the BlockSpec pipeline for every strip fetch and keeps
 # the whole (B, N, N) operand eligible for pipelining.  For images that
-# do NOT fit whole-image-in-VMEM (N >= 2048) the streamed variants below
-# process the image as ONE ``pallas_call`` with an explicit strip loop
-# and a VMEM scratch accumulator:
+# do NOT fit whole-image-in-VMEM (N >= 2048) the streamed kernel below
+# processes the image as ONE ``pallas_call``: the operand stays in HBM
+# (``memory_space=ANY``) and the kernel drives its own strip loop with
+# double-buffered ``pltpu.make_async_copy`` HBM->VMEM copies (2 strip
+# slots + 2 DMA semaphores): strip k+1's copy is launched before strip k
+# is consumed, so the Horner datapath hides the HBM fetch latency.
+# Exactly ONE strip buffer pair is live regardless of ceil(N/H) -- memory
+# is O(H*N), not O(N^2).
 #
-# * ``stream_impl="grid"`` -- the strip loop stays a grid dimension, but
-#   partial skew-sums accumulate into a VMEM scratch tile; ``out_ref`` is
-#   written exactly once, on the final strip (the interpret/CPU
-#   emulation of the DMA path: block-indexed strip fetches, identical
-#   numerics and revisit structure),
-# * ``stream_impl="dma"`` -- the operand stays in HBM
-#   (``memory_space=ANY``); the kernel drives its own strip loop with
-#   double-buffered ``pltpu.make_async_copy`` HBM->VMEM copies (2 strip
-#   slots + 2 DMA semaphores): strip k+1's copy is launched before strip
-#   k is consumed, so the Horner datapath hides the HBM fetch latency
-#   (the Mosaic path).  Exactly ONE strip buffer pair is live regardless
-#   of ceil(N/H) -- memory is O(H*N), not O(N^2).
-#
-# Both variants replace the plan layer's scan-of-launches ``block_rows``
-# fallback on pallas-capable backends: one launch, one jaxpr, partial
-# sums never round-tripping through HBM between strips.
+# It replaces the plan layer's scan-of-launches ``block_rows`` fallback on
+# pallas-capable backends: one launch, one jaxpr, partial sums never
+# round-tripping through HBM between strips.
 # ===========================================================================
-
-
-def _stream_grid_kernel(f_ref, *rest, n: int, n_pad: int, h: int,
-                        m_block: int, k_steps: int, mode: str,
-                        acc_dtype, step_impl: str, with_offset: bool):
-    """One (batch, m-block, strip) step of the streamed kernel, strip loop
-    on the grid: partial skew-sums accumulate in a VMEM scratch tile and
-    ``out_ref`` is written once, on the final strip."""
-    rest = list(rest)
-    off_ref = rest.pop(0) if with_offset else None
-    corr_ref = rest.pop(0) if mode == "inverse" else None
-    out_ref, acc_ref = rest
-    mb = pl.program_id(1)
-    k = pl.program_id(2)
-
-    zero = jnp.zeros((), acc_dtype)
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (m_block, 1), 0)
-    grow = mb * m_block + row_iota
-    valid = grow < n
-    m_vec = jnp.where(valid, grow, 0)
-    offset = k * h
-    if with_offset:
-        offset = offset + off_ref[0, 0]
-
-    acc = _strip_block_partial(
-        lambda j: f_ref[0, j, :], h=h, n=n, n_pad=n_pad, m_block=m_block,
-        m0=mb * m_block, m_vec=m_vec, valid=valid, offset=offset,
-        step_impl=step_impl, acc_dtype=acc_dtype)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = acc
-
-    @pl.when(k > 0)
-    def _accum():
-        acc_ref[...] = acc_ref[...] + acc
-
-    if mode == "forward":
-        # fused R(N, d) epilogue: this strip owns lanes [offset, offset+H)
-        @pl.when(mb == n // m_block)
-        def _rowsum():
-            rsum = jnp.sum(f_ref[0].astype(acc_dtype), axis=1, keepdims=True)
-            lane = jax.lax.broadcasted_iota(jnp.int32, (h, n_pad), 1)
-            srow = jax.lax.broadcasted_iota(jnp.int32, (h, n_pad), 0)
-            placed = jnp.sum(jnp.where(lane == offset + srow, rsum, zero),
-                             axis=0)
-            acc_ref[...] = acc_ref[...] + jnp.where(
-                grow == n, placed[None, :], zero)
-
-    @pl.when(k == k_steps - 1)
-    def _flush():
-        total = acc_ref[...]
-        if mode == "inverse":
-            total = total + corr_ref[0].astype(acc_dtype)
-            if jnp.issubdtype(jnp.dtype(acc_dtype), jnp.integer):
-                res = total // n
-            else:
-                res = total / n
-            out_ref[0] = jnp.where(valid, res, zero)
-        else:
-            out_ref[0] = total
 
 
 def _stream_dma_kernel(f_ref, *rest, n: int, n_pad: int, h: int,
                        m_block: int, k_steps: int, mode: str,
-                       acc_dtype, step_impl: str, with_offset: bool):
+                       acc_dtype, with_offset: bool):
     """One (batch, m-block) step of the streamed kernel, strip loop in
     the kernel: the operand stays in HBM (``memory_space=ANY``) and the
     ``fori_loop`` below double-buffers H-row strips into a 2-slot VMEM
@@ -540,7 +431,7 @@ def _stream_dma_kernel(f_ref, *rest, n: int, n_pad: int, h: int,
         acc = acc + _strip_block_partial(
             lambda j: buf_ref[slot, j, :], h=h, n=n, n_pad=n_pad,
             m_block=m_block, m0=mb * m_block, m_vec=m_vec, valid=valid,
-            offset=offset, step_impl=step_impl, acc_dtype=acc_dtype)
+            offset=offset, acc_dtype=acc_dtype)
         if mode == "forward":
             # fused R(N, d) epilogue while the strip is VMEM-resident;
             # mb is traced here (loop-carried value, not a ref), so the
@@ -572,85 +463,51 @@ def _stream_dma_kernel(f_ref, *rest, n: int, n_pad: int, h: int,
 def _pallas_stream_call(g: jnp.ndarray, *, sign: int, mode: str,
                         stream_rows: int, m_block: int, interpret: bool,
                         corr: jnp.ndarray | None = None,
-                        lane_pad: bool | None = None,
-                        step_impl: str | None = None,
-                        stream_impl: str | None = None,
                         row_offset: jnp.ndarray | int | None = None
                         ) -> jnp.ndarray:
     """Streamed fused pallas_call: like :func:`_pallas_skew_call` but the
-    strip loop accumulates into a VMEM scratch (``stream_impl="grid"``)
-    or is driven in-kernel with double-buffered HBM->VMEM DMA copies
-    (``stream_impl="dma"``, default off-interpret).  ``stream_rows`` is
-    the streamed strip height H; VMEM footprint is O(m_block*N + H*N)
-    per grid step regardless of ceil(N/H)."""
+    strip loop is driven in-kernel with double-buffered HBM->VMEM DMA
+    copies.  ``stream_rows`` is the streamed strip height H; VMEM
+    footprint is O(m_block*N + H*N) per grid step regardless of
+    ceil(N/H)."""
     b, rows, n = g.shape
     acc_dtype = g.dtype
     h = max(1, min(int(stream_rows), rows))
     k_steps = math.ceil(rows / h)
-    if lane_pad is None:
-        lane_pad = not interpret
-    step_impl = _resolve_step(step_impl, interpret)
-    if stream_impl is None:
-        stream_impl = "grid" if interpret else "dma"
-    if stream_impl not in ("grid", "dma"):
-        raise ValueError(f"stream_impl must be 'grid' or 'dma': "
-                         f"{stream_impl!r}")
-    n_pad = ((n + LANE - 1) // LANE) * LANE if lane_pad else n
+    n_pad = _padded_lanes(n)
     out_rows = n + 1 if mode == "forward" else n
     r_blocks = math.ceil(out_rows / m_block)
-    grid_rank = 3 if stream_impl == "grid" else 2
 
     gp = jnp.pad(g, ((0, 0), (0, k_steps * h - rows), (0, n_pad - n)))
-    if stream_impl == "grid":
-        in_specs = [pl.BlockSpec((1, h, n_pad), lambda bb, i, j: (bb, j, 0))]
-    else:
-        # the operand never enters the BlockSpec pipeline: it stays in
-        # HBM and the kernel DMAs strips on its own schedule
-        in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    # the operand never enters the BlockSpec pipeline: it stays in HBM
+    # and the kernel DMAs strips on its own schedule
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
     operands = [gp]
     with_offset = row_offset is not None
     if with_offset:
         off = jnp.asarray(row_offset, jnp.int32).reshape(1, 1)
-        in_specs.append(pl.BlockSpec(
-            (1, 1), (lambda bb, i, j: (0, 0)) if grid_rank == 3
-            else (lambda bb, i: (0, 0))))
+        in_specs.append(pl.BlockSpec((1, 1), lambda bb, i: (0, 0)))
         operands.append(off)
     if mode == "inverse":
         corr_p = jnp.pad(corr.astype(acc_dtype),
                          ((0, 0), (0, r_blocks * m_block - n)))[..., None]
-        in_specs.append(pl.BlockSpec(
-            (1, m_block, 1), (lambda bb, i, j: (bb, i, 0)) if grid_rank == 3
-            else (lambda bb, i: (bb, i, 0))))
+        in_specs.append(pl.BlockSpec((1, m_block, 1),
+                                     lambda bb, i: (bb, i, 0)))
         operands.append(corr_p)
 
-    kw = dict(n=n, n_pad=n_pad, h=h, m_block=m_block, k_steps=k_steps,
-              mode=mode, acc_dtype=acc_dtype, step_impl=step_impl,
-              with_offset=with_offset)
-    if stream_impl == "grid":
-        kernel = functools.partial(_stream_grid_kernel, **kw)
-        grid = (b, r_blocks, k_steps)
-        out_spec = pl.BlockSpec((1, m_block, n_pad),
-                                lambda bb, i, j: (bb, i, 0))
-        scratch = [pltpu.VMEM((m_block, n_pad), acc_dtype)]
-        cparams = None if interpret else _COMPILER_PARAMS
-    else:
-        kernel = functools.partial(_stream_dma_kernel, **kw)
-        grid = (b, r_blocks)
-        out_spec = pl.BlockSpec((1, m_block, n_pad), lambda bb, i: (bb, i, 0))
-        # exactly ONE double-buffer pair, however many strips stream
-        scratch = [pltpu.VMEM((2, h, n_pad), acc_dtype),
-                   pltpu.SemaphoreType.DMA((2,))]
-        cparams = None if interpret else _COMPILER_PARAMS_2D
-
     out = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_stream_dma_kernel, n=n, n_pad=n_pad, h=h,
+                          m_block=m_block, k_steps=k_steps, mode=mode,
+                          acc_dtype=acc_dtype, with_offset=with_offset),
+        grid=(b, r_blocks),
         in_specs=in_specs,
-        out_specs=out_spec,
+        out_specs=pl.BlockSpec((1, m_block, n_pad), lambda bb, i: (bb, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, r_blocks * m_block, n_pad),
                                        acc_dtype),
-        scratch_shapes=scratch,
-        compiler_params=cparams,
+        # exactly ONE double-buffer pair, however many strips stream
+        scratch_shapes=[pltpu.VMEM((2, h, n_pad), acc_dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=None if interpret else _COMPILER_PARAMS_2D,
         interpret=interpret,
         name=f"sfdprt_stream_{mode}",
     )(*operands)
@@ -659,13 +516,11 @@ def _pallas_stream_call(g: jnp.ndarray, *, sign: int, mode: str,
 
 @functools.partial(jax.jit,
                    static_argnames=("sign", "strip_rows", "m_block",
-                                    "interpret", "step_impl",
-                                    "stream_rows", "stream_impl"))
+                                    "interpret", "stream_rows"))
 def skew_sum_pallas_raw(g: jnp.ndarray, sign: int = 1, strip_rows: int = 16,
                         m_block: int = 8, interpret: bool = True,
-                        step_impl: str | None = None,
-                        row_offset=None, stream_rows: int | None = None,
-                        stream_impl: str | None = None) -> jnp.ndarray:
+                        row_offset=None,
+                        stream_rows: int | None = None) -> jnp.ndarray:
     """Bare skew_sum via the strip kernel (core mode, no fused epilogue).
 
     g: (rows, N) or a batched (B, rows, N) stack, N prime.  Returns the
@@ -688,27 +543,21 @@ def skew_sum_pallas_raw(g: jnp.ndarray, sign: int = 1, strip_rows: int = 16,
     if stream_rows is not None:
         out = _pallas_stream_call(ga, sign=sign, mode="core",
                                   stream_rows=stream_rows, m_block=m_block,
-                                  interpret=interpret, step_impl=step_impl,
-                                  stream_impl=stream_impl,
-                                  row_offset=row_offset)
+                                  interpret=interpret, row_offset=row_offset)
     else:
         out = _pallas_skew_call(ga, sign=sign, mode="core",
                                 strip_rows=strip_rows, m_block=m_block,
-                                interpret=interpret, step_impl=step_impl,
-                                row_offset=row_offset)
+                                interpret=interpret, row_offset=row_offset)
     out = out[:, :n, :n]
     return out[0] if single else out
 
 
 @functools.partial(jax.jit,
                    static_argnames=("strip_rows", "m_block", "interpret",
-                                    "step_impl", "stream_rows",
-                                    "stream_impl"))
+                                    "stream_rows"))
 def dprt_pallas_raw(f: jnp.ndarray, strip_rows: int = 16, m_block: int = 8,
-                    interpret: bool = True,
-                    step_impl: str | None = None,
-                    row_offset=None, stream_rows: int | None = None,
-                    stream_impl: str | None = None) -> jnp.ndarray:
+                    interpret: bool = True, row_offset=None,
+                    stream_rows: int | None = None) -> jnp.ndarray:
     """Fused batched forward DPRT: (B, N, N) -> (B, N+1, N) in ONE
     pallas_call; the R(N, d) row-sum row is produced by the in-kernel
     epilogue rather than a second pass over the image.
@@ -723,26 +572,20 @@ def dprt_pallas_raw(f: jnp.ndarray, strip_rows: int = 16, m_block: int = 8,
     if stream_rows is not None:
         out = _pallas_stream_call(fa, sign=1, mode="forward",
                                   stream_rows=stream_rows, m_block=m_block,
-                                  interpret=interpret, step_impl=step_impl,
-                                  stream_impl=stream_impl,
-                                  row_offset=row_offset)
+                                  interpret=interpret, row_offset=row_offset)
     else:
         out = _pallas_skew_call(fa, sign=1, mode="forward",
                                 strip_rows=strip_rows, m_block=m_block,
-                                interpret=interpret, step_impl=step_impl,
-                                row_offset=row_offset)
+                                interpret=interpret, row_offset=row_offset)
     return out[:, :n + 1, :n]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("strip_rows", "m_block", "interpret",
-                                    "step_impl", "stream_rows",
-                                    "stream_impl"))
+                                    "stream_rows"))
 def idprt_pallas_raw(r: jnp.ndarray, strip_rows: int = 16, m_block: int = 8,
                      interpret: bool = True,
-                     step_impl: str | None = None,
-                     stream_rows: int | None = None,
-                     stream_impl: str | None = None) -> jnp.ndarray:
+                     stream_rows: int | None = None) -> jnp.ndarray:
     """Fused batched inverse DPRT: (B, N+1, N) -> (B, N, N) in ONE
     pallas_call; the -S + R(N, i) correction and exact divide-by-N run
     in-kernel on the final strip (no post-kernel pass)."""
@@ -753,14 +596,11 @@ def idprt_pallas_raw(r: jnp.ndarray, strip_rows: int = 16, m_block: int = 8,
     if stream_rows is not None:
         out = _pallas_stream_call(ra[:, :n, :], sign=-1, mode="inverse",
                                   stream_rows=stream_rows, m_block=m_block,
-                                  interpret=interpret, corr=corr,
-                                  step_impl=step_impl,
-                                  stream_impl=stream_impl)
+                                  interpret=interpret, corr=corr)
     else:
         out = _pallas_skew_call(ra[:, :n, :], sign=-1, mode="inverse",
                                 strip_rows=strip_rows, m_block=m_block,
-                                interpret=interpret, corr=corr,
-                                step_impl=step_impl)
+                                interpret=interpret, corr=corr)
     return out[:, :n, :n]
 
 
@@ -785,17 +625,16 @@ isfdprt_core = functools.partial(skew_sum_pallas_raw, sign=-1)
 # image.  The (N+1, N) projections never exist outside VMEM/registers;
 # MEM_OUT is only ever the final (N, N) image.
 #
-# **Horner step.**  Compiled (``step_impl="roll"``), both Horner stages
-# take the strip kernels' step (:func:`_strip_block_partial`): the
-# accumulator is a periodic extension on ``2*n_pad`` lanes, and each cycle
-# is ONE strided lane rotate whose amount is linear in the sublane with a
-# base that is a multiple of 8 (:func:`_roll_horner`).  The forward
-# stage's sublane r rolls by ``m0 + r`` -- the CRS direction -- and reads
-# the image rows in ``<-j>_N`` order, which makes each CRS row the forward
-# direction of the same index; the inverse stage's output row ``i0 + r``
-# rolls by ``i0 + r``.  The once-per-block eq. (7) alignment, which holds
-# the traced direction offset, stays on :func:`apply_roll_ladder`.  In
-# interpret mode (``"permute"``) every roll is one hoisted gather.
+# **Horner step.**  Both Horner stages take the strip kernels' step
+# (:func:`_strip_block_partial`): the accumulator is a periodic extension
+# on ``2*n_pad`` lanes, and each cycle is ONE strided lane rotate whose
+# amount is linear in the sublane with a base that is a multiple of 8
+# (:func:`_roll_horner`).  The forward stage's sublane r rolls by
+# ``m0 + r`` -- the CRS direction -- and reads the image rows in ``<-j>_N``
+# order, which makes each CRS row the forward direction of the same index;
+# the inverse stage's output row ``i0 + r`` rolls by ``i0 + r``.  The
+# once-per-block eq. (7) alignment, which holds the traced direction
+# offset, stays on :func:`apply_roll_ladder`.  One image a grid step.
 #
 # The -S + R'(N, i) correction and exact /N divide need two *global* rows of
 # the convolved projections (row 0 for S, row N for the correction column);
@@ -803,13 +642,6 @@ isfdprt_core = functools.partial(skew_sum_pallas_raw, sign=-1)
 # m-blocks pass through, and the final m-block applies the whole correction
 # in-kernel -- or leaves it to the caller (``defer=True``, the mesh-sharded
 # path, where the division must wait for the cross-device ``psum``).
-#
-# **Batch-in-lanes** (interpret only).  A batched stack packs
-# ``lane_batch`` images side by side along the lane axis (segment s owns
-# lanes [s*n_pad, (s+1)*n_pad)); every gather/select then acts per
-# segment, so transforming LB images costs the same op count as one image
-# with LB-times-wider tiles.  The compiled step carries one image a grid
-# step (``lane_batch=1``).
 #
 # **Tail mode** (``source="proj"``).  The input rows are already-assembled
 # projection rows (a shard of directions, first global direction
@@ -823,37 +655,12 @@ isfdprt_core = functools.partial(skew_sum_pallas_raw, sign=-1)
 PIPELINE_OPS = ("none", "mul", "conv")
 
 
-def _seg_perm(amt, n: int, n_pad: int, lb: int, rows_out: int) -> jnp.ndarray:
-    """Per-segment rotation gather index for a wide (rows_out, lb*n_pad)
-    tile: idx[r, s*n_pad + d] = s*n_pad + <d + amt[r]>_n for d < n,
-    identity on each segment's zero tail.  ``amt`` is (rows_out, 1) in
-    [0, n)."""
-    d = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_pad), 2)
-    base = jax.lax.broadcasted_iota(jnp.int32, (1, lb, 1), 1) * n_pad
-    rot = d + amt[:, :, None]                 # (rows_out, 1, n_pad)
-    rot = jnp.where(rot >= n, rot - n, rot)
-    rot = jnp.where(d >= n, d, rot)
-    return jnp.broadcast_to(rot + base, (rows_out, lb, n_pad)).reshape(
-        rows_out, lb * n_pad)
-
-
-def _seg_roller(amt, n: int, n_pad: int, lb: int, rows_out: int):
-    """The hoisted per-step roll of the interpret (``"permute"``) step: a
-    closure applying out[r, s, d] = acc[r, s, <d + amt[r]>_n] to a wide
-    tile with ONE gather."""
-    idx = _seg_perm(amt, n, n_pad, lb, rows_out)
-
-    def roll(acc):
-        return jnp.take_along_axis(acc, idx, axis=1)
-    return roll
-
-
 def _roll_horner(read, cycles: int, rows_out: int, base, n: int,
                  n_pad: int, acc_dtype) -> jnp.ndarray:
-    """The compiled Horner loop of the pipeline's forward and inverse
-    stages: ``cycles`` cycles of ``T[r] <- roll(T[r], base + r) +
-    read(c)``, the roll at logical width N in the ``jnp.roll`` sense
-    (lane d reads ``T[r, <d - base - r>_N]``).
+    """The Horner loop of the pipeline's forward and inverse stages:
+    ``cycles`` cycles of ``T[r] <- roll(T[r], base + r) + read(c)``, the
+    roll at logical width N in the ``jnp.roll`` sense (lane d reads
+    ``T[r, <d - base - r>_N]``).
 
     As in :func:`_strip_block_partial`, T lives on lanes
     ``[n_pad, n_pad + N)`` of a ``2*n_pad`` lane tile with a copy on the
@@ -879,46 +686,46 @@ def _roll_horner(read, cycles: int, rows_out: int, base, n: int,
     return ext[:, n_pad:]
 
 
-def _seg_roll_static(acc3: jnp.ndarray, k: int, n: int) -> jnp.ndarray:
-    """Rotate every segment of a (rows, lb, n_pad) -- or, one image, a
-    (rows, n_pad) -- tile right by the *static* amount k at logical
-    width n along its last axis (zero tails carried through)."""
+def _seg_roll_static(acc: jnp.ndarray, k: int, n: int) -> jnp.ndarray:
+    """Rotate every row of a (rows, n_pad) tile right by the *static*
+    amount k at logical width n along its last axis (zero tails carried
+    through)."""
     k %= n
     if k == 0:
-        return acc3
-    parts = [acc3[..., n - k:n], acc3[..., :n - k], acc3[..., n:]]
+        return acc
+    parts = [acc[..., n - k:n], acc[..., :n - k], acc[..., n:]]
     return jnp.concatenate([p for p in parts if p.shape[-1]], axis=-1)
 
 
 def _conv_epilogue(rf: jnp.ndarray, rg3: jnp.ndarray, n: int, n_pad: int,
-                   lb: int, group: int, acc_dtype) -> jnp.ndarray:
+                   group: int, acc_dtype) -> jnp.ndarray:
     """In-register per-direction 1-D circular convolution (Horner form):
 
-        rc[m, s, d] = sum_t rf[m, s, t] * rg[m, s|0, <d - t>_n]
+        rc[m, d] = sum_t rf[m, t] * rg[m, <d - t>_n]
 
-    K taps are consumed per cycle against K statically pre-rotated copies
-    of the operand rows, so the loop body is K multiply-adds plus ONE
-    static rotate-by-K of the accumulator -- no gathers, no index math.
-    The cycle's taps sit in lanes [0, K) of a carried copy of ``rf``
-    that rotates by K per cycle, so every lane index is static (Mosaic
-    lowers no dynamic lane slice of a value).
+    ``rf`` is (m_block, n_pad); ``rg3`` the operand rows as
+    (m_block, 1, n_pad).  K taps are consumed per cycle against K
+    statically pre-rotated copies of the operand rows, so the loop body
+    is K multiply-adds plus ONE static rotate-by-K of the accumulator --
+    no gathers, no index math.  The cycle's taps sit in lanes [0, K) of a
+    carried copy of ``rf`` that rotates by K per cycle, so every lane
+    index is static (Mosaic lowers no dynamic lane slice of a value).
+    All tiles are 2-D: a (rows, 1, lanes) view pads each row to a whole
+    8-sublane tile.
     """
     m_block = rf.shape[0]
     k = max(1, min(group, n - 1))
-    # one image a tile (the compiled step): stay 2-D, since a (rows, 1,
-    # lanes) view pads each row to a whole 8-sublane tile
-    seg = (m_block, lb, n_pad) if lb > 1 else (m_block, n_pad)
-    rf3 = rf.reshape(seg)
-    rgs = [rg3.reshape(seg) if lb == 1 else rg3]
+    rgs = [rg3.reshape(m_block, n_pad)]
     for _ in range(1, k):
         rgs.append(_seg_roll_static(rgs[-1], 1, n))
     nk = math.ceil(n / k)
     width = max(n_pad, nk * k)
+    taps = rf
     if width > n_pad:         # taps beyond the lane pad: zero (rf tail is 0)
-        rf3 = jnp.pad(rf3, [(0, 0)] * (rf3.ndim - 1) + [(0, width - n_pad)])
+        taps = jnp.pad(taps, [(0, 0), (0, width - n_pad)])
     # cycle j consumes taps [t0, t0 + K) with t0 = (nk - 1 - j) * K: start
     # rotated left by the first t0, then rotate right by K per cycle
-    taps = _seg_roll_static(rf3, width - (nk - 1) * k, width)
+    taps = _seg_roll_static(taps, width - (nk - 1) * k, width)
 
     def body(_, carry):
         acc, taps = carry
@@ -927,17 +734,16 @@ def _conv_epilogue(rf: jnp.ndarray, rg3: jnp.ndarray, n: int, n_pad: int,
             acc = acc + taps[..., u:u + 1] * rgs[u]
         return acc, _seg_roll_static(taps, k, width)
 
-    acc = jnp.zeros(seg, acc_dtype)
+    acc = jnp.zeros((m_block, n_pad), acc_dtype)
     acc, _ = jax.lax.fori_loop(0, nk, body, (acc, taps))
-    return acc.reshape(m_block, lb * n_pad)
+    return acc
 
 
 def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
-                     nr_pad: int, mb_total: int, lb: int, op: str,
-                     source: str, operand_form: str, w_wide: bool,
-                     defer: bool, acc_dtype, group: int, step_impl: str,
+                     nr_pad: int, mb_total: int, op: str, source: str,
+                     operand_form: str, defer: bool, acc_dtype, group: int,
                      with_offset: bool):
-    """One (lane-group, m-block) grid step of the fused pipeline."""
+    """One (image, m-block) grid step of the fused pipeline."""
     refs = list(refs)
     off_ref = refs.pop(0) if with_offset else None
     f_ref = refs.pop(0)
@@ -947,16 +753,14 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
         else None
     out_ref, aux_ref, rc_ref = refs
     # one count per body traced, credited to the executable being built:
-    # which roll step it took, and whether every grid step re-runs the
+    # the strided-rotate step, and whether every grid step re-runs the
     # conv operand's forward
-    if step_impl == "roll":
-        spans.count("sfdprt_pipeline_step_roll")
+    spans.count("sfdprt_pipeline_step_roll")
     if g_ref is not None:
         spans.count("sfdprt_pipeline_operand_fwd")
 
     mb = pl.program_id(1)
     zero = jnp.zeros((), acc_dtype)
-    wide = lb * n_pad
 
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (m_block, 1), 0)
     dir0 = mb * m_block
@@ -964,47 +768,35 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
         dir0 = dir0 + off_ref[0, 0]
     grow = dir0 + row_iota                    # global direction row
     valid_fwd = grow < n
-    m_vec = jnp.where(valid_fwd, grow, 0)
     last = mb == mb_total - 1
-    # lanes >= N: zero tails under "permute", leftovers under "roll"
-    # (which runs one image, lb == 1)
-    in_lanes = (jax.lax.broadcasted_iota(jnp.int32, (1, wide), 1) < n
-                if step_impl == "roll" else True)
+    # lanes >= N hold leftovers of the rotate
+    in_lanes = jax.lax.broadcasted_iota(jnp.int32, (1, n_pad), 1) < n
 
     # ---- forward stage: whole-rows Horner per direction block ------------
     def fwd_of(x_ref):
-        if step_impl == "roll":
-            # the CRS skew sum of the rows taken in <-j>_N order,
-            # f(<-j>, .), is the forward skew sum of f: cycle c adds row
-            # <c + 1>_N, which the remaining cycles roll by
-            # (rows - 1 - c) * m = -(c + 1) * m
-            def read(c):
-                j = jnp.where(c + 1 == rows, 0, c + 1)
-                return x_ref[0, j, :][None, :].astype(acc_dtype)
+        # the CRS skew sum of the rows taken in <-j>_N order, f(<-j>, .),
+        # is the forward skew sum of f: cycle c adds row <c + 1>_N, which
+        # the remaining cycles roll by (rows - 1 - c) * m = -(c + 1) * m
+        def read(c):
+            j = jnp.where(c + 1 == rows, 0, c + 1)
+            return x_ref[0, j, :][None, :].astype(acc_dtype)
 
-            acc = _roll_horner(read, rows, m_block, dir0, n, n_pad,
-                               acc_dtype)
-            return jnp.where(valid_fwd & in_lanes, acc, zero)
-        roll = _seg_roller(m_vec, n, n_pad, lb, m_block)
-
-        def body(i, acc):
-            row = x_ref[0, rows - 1 - i, :]
-            return roll(acc) + row[None, :].astype(acc_dtype)
-
-        acc = jax.lax.fori_loop(0, rows, body,
-                                jnp.zeros((m_block, wide), acc_dtype))
-        return jnp.where(valid_fwd, acc, zero)
+        acc = _roll_horner(read, rows, m_block, dir0, n, n_pad, acc_dtype)
+        return jnp.where(valid_fwd & in_lanes, acc, zero)
 
     def rowsum_of(x_ref):
-        # R(N, d): each image row's sum placed at its own lane -- per
-        # segment -- and dropped into the grow == n direction slot.
-        x3 = x_ref[0].reshape(rows, lb, n_pad)
+        # R(N, d): each image row's sum placed at its own lane and dropped
+        # into the grow == n direction slot.  These (rows, 1, n_pad)
+        # views, like those of the operand rows and of the final
+        # correction, are part of the kernel as measured on the chip:
+        # dropping the unit axis changes the Mosaic kernel, which would
+        # then need a chip run of its own
+        x3 = x_ref[0].reshape(rows, 1, n_pad)
         lane = jax.lax.broadcasted_iota(jnp.int32, (rows, 1, n_pad), 2)
         rsum = jnp.sum(jnp.where(lane < n, x3.astype(acc_dtype), zero),
-                       axis=2)[:, :, None]               # (rows, lb, 1)
+                       axis=2)[:, :, None]               # (rows, 1, 1)
         srow = jax.lax.broadcasted_iota(jnp.int32, (rows, 1, n_pad), 0)
-        placed = jnp.sum(jnp.where(lane == srow, rsum, zero),
-                         axis=0).reshape(1, wide)        # (1, lb*n_pad)
+        placed = jnp.sum(jnp.where(lane == srow, rsum, zero), axis=0)
         return jnp.where(grow == n, placed, zero)
 
     # the row-sum row lives in exactly one m-block; pay for its placement
@@ -1020,23 +812,15 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
         rf = with_rowsum(fwd_of(f_ref), f_ref)
 
     # ---- per-direction epilogue ------------------------------------------
-    def w_block3():
-        """This block's operand rows as (m_block, lb|1, n_pad)."""
-        rows_w = w_ref[0].astype(acc_dtype)
-        if w_wide:
-            return rows_w.reshape(m_block, lb, n_pad)
-        return rows_w[:, None, :]
-
     if op == "conv":
         if operand_form == "image":
-            rg3 = with_rowsum(fwd_of(g_ref), g_ref).reshape(
-                m_block, lb, n_pad)
+            rg3 = with_rowsum(fwd_of(g_ref), g_ref).reshape(m_block, 1,
+                                                            n_pad)
         else:
-            rg3 = w_block3()
-        rc = _conv_epilogue(rf, rg3, n, n_pad, lb, group, acc_dtype)
+            rg3 = w_ref[0].astype(acc_dtype)[:, None, :]
+        rc = _conv_epilogue(rf, rg3, n, n_pad, group, acc_dtype)
     elif op == "mul":
-        rc = (rf.reshape(m_block, lb, n_pad) * w_block3()).reshape(
-            m_block, wide)
+        rc = rf * w_ref[0].astype(acc_dtype)
     else:
         rc = rf
 
@@ -1057,8 +841,8 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
     # ---- inverse stage: this block's directions onto ALL image rows ------
     # The output rows are processed in sub-blocks of 256 (a multiple of
     # 8, so each sub-block's first row is a valid base of the strided
-    # rotate): one (IB, wide) accumulator stays resident per sub-block
-    # instead of a single (nr_pad, wide) mega-tile at giant N.
+    # rotate): one (IB, n_pad) accumulator stays resident per sub-block
+    # instead of a single (nr_pad, n_pad) mega-tile at giant N.
     # staged in VMEM scratch: the Horner below reads one direction row
     # per cycle at a traced index, which Mosaic lowers only from a ref
     rc_ref[...] = jnp.where(valid_fwd, rc, zero)
@@ -1075,22 +859,11 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
 
         # Horner over the block's directions: output row i rolls by i a
         # cycle (z[i, d] gathers rc[k, <d - i*k>_N])
-        if step_impl == "roll":
-            z = _roll_horner(read, m_block, rows_ib, i0, n, n_pad,
-                             acc_dtype)
-        else:
-            neg_i = jnp.where(i_vec == 0, 0, n - i_vec)
-            roll_inv = _seg_roller(neg_i, n, n_pad, lb, rows_ib)
-            z = jax.lax.fori_loop(
-                0, m_block, lambda t, acc: roll_inv(acc) + read(t),
-                jnp.zeros((rows_ib, wide), acc_dtype))
+        z = _roll_horner(read, m_block, rows_ib, i0, n, n_pad, acc_dtype)
         # alignment: the Horner above assumed the block's first direction
         # is 0; roll each output row i by <-i * dir0>_n (eq. 7, m -> i)
         align_amt = jnp.mod(-i_vec * (dir0 % n), n)
-        if step_impl == "roll":
-            z = apply_roll_ladder(z, ladder_select_masks(align_amt, n), n)
-        else:
-            z = _seg_roller(align_amt, n, n_pad, lb, rows_ib)(z)
+        z = apply_roll_ladder(z, ladder_select_masks(align_amt, n), n)
         zs.append(jnp.where(i_valid & in_lanes, z, zero))
     z = jnp.concatenate(zs, axis=0) if len(zs) > 1 else zs[0]
 
@@ -1105,51 +878,32 @@ def _pipeline_kernel(*refs, n: int, n_pad: int, rows: int, m_block: int,
     if not defer:
         @pl.when(last)
         def _final():
-            # f = (Z - S + R'(N, i)) / N per segment, exact for integers
-            aux3 = aux_ref[0, :2].reshape(2, lb, n_pad)
+            # f = (Z - S + R'(N, i)) / N, exact for integers
+            aux3 = aux_ref[0, :2].reshape(2, 1, n_pad)
             lane = jax.lax.broadcasted_iota(jnp.int32, (nr_pad, 1, n_pad), 2)
             srow = jax.lax.broadcasted_iota(jnp.int32, (nr_pad, 1, n_pad), 0)
             s = jnp.sum(jnp.where(lane[0] < n, aux3[0], zero),
-                        axis=1)[None, :, None]            # (1, lb, 1)
+                        axis=1)[None, :, None]            # (1, 1, 1)
             cn = jnp.sum(jnp.where(lane == srow, aux3[1][None], zero),
-                         axis=2, keepdims=True)           # (nr_pad, lb, 1)
-            num = out_ref[0].reshape(nr_pad, lb, n_pad) - s + cn
+                         axis=2, keepdims=True)           # (nr_pad, 1, 1)
+            num = out_ref[0].reshape(nr_pad, 1, n_pad) - s + cn
             if jnp.issubdtype(jnp.dtype(acc_dtype), jnp.integer):
                 res = num // n
             else:
                 res = num / n
             keep = (srow < n) & (lane < n)
-            out_ref[0] = jnp.where(keep, res, zero).reshape(nr_pad, wide)
-
-
-def _pack_lanes(x: jnp.ndarray, lb: int, n_pad: int) -> jnp.ndarray:
-    """(B, rows, N) -> (ceil(B/lb), rows, lb*n_pad) batch-in-lanes layout
-    (zero images pad the last group; zero lane tails pad each segment)."""
-    b, rows, n = x.shape
-    bg = math.ceil(b / lb)
-    x = jnp.pad(x, ((0, bg * lb - b), (0, 0), (0, n_pad - n)))
-    return jnp.transpose(x.reshape(bg, lb, rows, n_pad),
-                         (0, 2, 1, 3)).reshape(bg, rows, lb * n_pad)
-
-
-def _unpack_lanes(y: jnp.ndarray, b: int, lb: int, n_pad: int) -> jnp.ndarray:
-    """(BG, rows, lb*n_pad) -> (B, rows, n_pad): inverse of _pack_lanes."""
-    bg, rows, _ = y.shape
-    y = jnp.transpose(y.reshape(bg, rows, lb, n_pad), (0, 2, 1, 3))
-    return y.reshape(bg * lb, rows, n_pad)[:b]
+            out_ref[0] = jnp.where(keep, res, zero).reshape(nr_pad, n_pad)
 
 
 @functools.partial(
     jax.jit, static_argnames=("op", "operand_form", "source", "m_block",
-                              "group", "lane_batch", "defer", "interpret",
-                              "step_impl", "n_rows"))
+                              "group", "defer", "interpret", "n_rows"))
 def pipeline_pallas_raw(f: jnp.ndarray, operand: jnp.ndarray | None = None,
                         op: str = "none", operand_form: str = "proj",
                         source: str = "image", m_block: int = 32,
-                        group: int = 4, lane_batch: int = 1,
-                        defer: bool = False, interpret: bool = True,
-                        step_impl: str | None = None,
-                        row_offset=None, n_rows: int | None = None
+                        group: int = 4, defer: bool = False,
+                        interpret: bool = True, row_offset=None,
+                        n_rows: int | None = None
                         ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The fused projection-domain pipeline in ONE ``pallas_call``.
 
@@ -1162,14 +916,9 @@ def pipeline_pallas_raw(f: jnp.ndarray, operand: jnp.ndarray | None = None,
     batch.  Returns ``(out, aux)`` where out is (B, nr_pad, n_pad) --
     the reconstruction (or, with ``defer=True``, the raw inverse-ladder
     partial Z) -- and aux is (B, 2, n_pad) holding the convolved rows 0
-    and N for the deferred -S + R'(N, i) correction.  Callers slice to
-    (…, N, N).  ``n_rows`` is the transform size N when ``source="proj"``
-    rows don't imply it.
-
-    ``step_impl`` (default: ``"permute"`` in interpret mode, ``"roll"``
-    compiled) picks the Horner step.  ``"roll"`` runs on the chip's
-    layout in interpret mode too: lanes padded to a 128-multiple, one
-    image a grid step (``lane_batch=1``).
+    and N for the deferred -S + R'(N, i) correction.  Lanes are padded
+    to a 128-multiple; callers slice to (…, N, N).  ``n_rows`` is the
+    transform size N when ``source="proj"`` rows don't imply it.
     """
     if op not in PIPELINE_OPS:
         raise ValueError(f"pipeline op must be one of {PIPELINE_OPS}: {op!r}")
@@ -1177,31 +926,20 @@ def pipeline_pallas_raw(f: jnp.ndarray, operand: jnp.ndarray | None = None,
     if source == "proj":
         n = f.shape[-1] if n_rows is None else n_rows
     acc_dtype = f.dtype
-    lb = max(1, min(int(lane_batch), b))
-    step_impl = _resolve_step(step_impl, interpret)
-    if step_impl == "roll" and lb > 1:
-        raise ValueError(f"the compiled pipeline step takes one image a "
-                         f"grid step: lane_batch must be 1, got {lb}")
-    lane_pad = not interpret or step_impl == "roll"
-    n_pad = ((n + LANE - 1) // LANE) * LANE if lane_pad else n
+    n_pad = _padded_lanes(n)
     nr_pad = ((n + 7) // 8) * 8
-    bg = math.ceil(b / lb)
-    wide = lb * n_pad
 
     if source == "proj":
         mb_total = math.ceil(rows / m_block)
         rows_pad = mb_total * m_block
-        fp4 = jnp.pad(f, ((0, bg * lb - b), (0, rows_pad - rows),
-                          (0, n_pad - n)))
-        fp = jnp.transpose(fp4.reshape(bg, lb, rows_pad, n_pad),
-                           (0, 2, 1, 3)).reshape(bg, rows_pad, wide)
-        in_specs = [pl.BlockSpec((1, m_block, wide),
+        fp = jnp.pad(f, ((0, 0), (0, rows_pad - rows), (0, n_pad - n)))
+        in_specs = [pl.BlockSpec((1, m_block, n_pad),
                                  lambda bb, i: (bb, i, 0))]
         defer = True                      # correction needs the global psum
     else:
         mb_total = math.ceil((n + 1) / m_block)
-        fp = _pack_lanes(f, lb, n_pad)
-        in_specs = [pl.BlockSpec((1, rows, wide), lambda bb, i: (bb, 0, 0))]
+        fp = jnp.pad(f, ((0, 0), (0, 0), (0, n_pad - n)))
+        in_specs = [pl.BlockSpec((1, rows, n_pad), lambda bb, i: (bb, 0, 0))]
 
     operands = [fp]
     with_offset = row_offset is not None
@@ -1210,18 +948,13 @@ def pipeline_pallas_raw(f: jnp.ndarray, operand: jnp.ndarray | None = None,
         in_specs.insert(0, pl.BlockSpec((1, 1), lambda bb, i: (0, 0)))
         operands.insert(0, off)
 
-    w_wide = False
+    # a batched operand follows the image's grid step; a shared one
+    # (leading axis 1) is read at image 0 by every step
     if op == "conv" and operand_form == "image":
-        gb = operand
-        if gb.shape[0] == b:
-            gp = _pack_lanes(gb, lb, n_pad)
-            in_specs.append(pl.BlockSpec((1, rows, wide),
-                                         lambda bb, i: (bb, 0, 0)))
-        else:   # one shared operand image, tiled across segments
-            gp = _pack_lanes(jnp.broadcast_to(gb, (lb, *gb.shape[1:])),
-                             lb, n_pad)
-            in_specs.append(pl.BlockSpec((1, rows, wide),
-                                         lambda bb, i: (0, 0, 0)))
+        gp = jnp.pad(operand, ((0, 0), (0, 0), (0, n_pad - n)))
+        in_specs.append(pl.BlockSpec(
+            (1, rows, n_pad), (lambda bb, i: (bb, 0, 0)) if gp.shape[0] == b
+            else (lambda bb, i: (0, 0, 0))))
         operands.append(gp.astype(acc_dtype))
     elif op == "mul" or (op == "conv" and operand_form == "proj"):
         wb = operand if operand.ndim == 3 else operand[None]
@@ -1237,40 +970,27 @@ def pipeline_pallas_raw(f: jnp.ndarray, operand: jnp.ndarray | None = None,
                 wb, jnp.asarray(0 if row_offset is None else row_offset,
                                 jnp.int32), rows_pad, axis=1)
         w_rows = mb_total * m_block
-        if wb.shape[0] == b and b > 1:
-            w_wide = True
-            wp = jnp.pad(wb, ((0, bg * lb - b), (0, w_rows - wb.shape[1]),
-                              (0, n_pad - n)))
-            wp = jnp.transpose(wp.reshape(bg, lb, w_rows, n_pad),
-                               (0, 2, 1, 3)).reshape(bg, w_rows, wide)
-            in_specs.append(pl.BlockSpec((1, m_block, wide),
-                                         lambda bb, i: (bb, i, 0)))
-        else:
-            wp = jnp.pad(wb[0], ((0, w_rows - wb.shape[1]),
-                                 (0, n_pad - n)))[None]
-            in_specs.append(pl.BlockSpec((1, m_block, n_pad),
-                                         lambda bb, i: (0, i, 0)))
+        wp = jnp.pad(wb, ((0, 0), (0, w_rows - wb.shape[1]), (0, n_pad - n)))
+        in_specs.append(pl.BlockSpec(
+            (1, m_block, n_pad), (lambda bb, i: (bb, i, 0))
+            if wp.shape[0] == b and b > 1 else (lambda bb, i: (0, i, 0))))
         operands.append(wp.astype(acc_dtype))
-
-    cparams = None if interpret else _COMPILER_PARAMS_2D
 
     out, aux = pl.pallas_call(
         functools.partial(
             _pipeline_kernel, n=n, n_pad=n_pad, rows=rows,
-            m_block=m_block, nr_pad=nr_pad, mb_total=mb_total, lb=lb,
-            op=op, source=source, operand_form=operand_form, w_wide=w_wide,
-            defer=defer, acc_dtype=acc_dtype, group=group,
-            step_impl=step_impl, with_offset=with_offset),
-        grid=(bg, mb_total),
+            m_block=m_block, nr_pad=nr_pad, mb_total=mb_total, op=op,
+            source=source, operand_form=operand_form, defer=defer,
+            acc_dtype=acc_dtype, group=group, with_offset=with_offset),
+        grid=(b, mb_total),
         in_specs=in_specs,
-        out_specs=(pl.BlockSpec((1, nr_pad, wide), lambda bb, i: (bb, 0, 0)),
-                   pl.BlockSpec((1, 8, wide), lambda bb, i: (bb, 0, 0))),
-        out_shape=(jax.ShapeDtypeStruct((bg, nr_pad, wide), acc_dtype),
-                   jax.ShapeDtypeStruct((bg, 8, wide), acc_dtype)),
-        scratch_shapes=[pltpu.VMEM((m_block, wide), acc_dtype)],
-        compiler_params=cparams,
+        out_specs=(pl.BlockSpec((1, nr_pad, n_pad), lambda bb, i: (bb, 0, 0)),
+                   pl.BlockSpec((1, 8, n_pad), lambda bb, i: (bb, 0, 0))),
+        out_shape=(jax.ShapeDtypeStruct((b, nr_pad, n_pad), acc_dtype),
+                   jax.ShapeDtypeStruct((b, 8, n_pad), acc_dtype)),
+        scratch_shapes=[pltpu.VMEM((m_block, n_pad), acc_dtype)],
+        compiler_params=None if interpret else _COMPILER_PARAMS_2D,
         interpret=interpret,
         name=f"sfdprt_pipeline_{op}",
     )(*operands)
-    return (_unpack_lanes(out, b, lb, n_pad),
-            _unpack_lanes(aux, b, lb, n_pad)[:, :2])
+    return out, aux[:, :2]

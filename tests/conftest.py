@@ -1,4 +1,3 @@
-import functools
 import importlib.util
 import json
 import os
@@ -71,25 +70,17 @@ def conv251_kernel():
 
 
 @pytest.fixture
-def pipeline_step(monkeypatch):
-    """``pipeline_step(step_impl)``: drop every cached plan, executable
-    and trace of the fused pipeline kernel, so the next build traces its
-    body anew -- with ``step_impl`` forced on every call when given
-    (``None``: the kernel's own default).  The caches are dropped again
-    after the test."""
+def pipeline_step():
+    """``pipeline_step()``: drop every cached plan, executable and trace
+    of the fused pipeline kernel, so the next build traces its body anew
+    (and credits its counters to that build).  The caches are dropped
+    again after the test."""
     from repro import radon
-    from repro.kernels import ops, sfdprt
+    from repro.kernels import sfdprt
 
     def clear():
         radon.aot_cache_clear()
         radon.plan_cache_clear()
         sfdprt.pipeline_pallas_raw.clear_cache()
-
-    def fresh(step_impl=None):
-        clear()
-        raw = sfdprt.pipeline_pallas_raw
-        if step_impl is not None:
-            raw = functools.partial(raw, step_impl=step_impl)
-        monkeypatch.setattr(ops, "pipeline_pallas_raw", raw)
-    yield fresh
-    clear()             # no later test reuses a trace of a forced step
+    yield clear
+    clear()

@@ -6,10 +6,11 @@
   pallas_call per stack),
 * the hoisted-setup contract: mask derivation happens once per
   m-block, never inside the Horner loop,
-* the compiled per-cycle step (one strided lane rotate on a periodically
-  extended accumulator) forced in interpret mode with padded lanes,
-  bit-exact against the oracles, and its loop body's shape -- in the
-  strip kernels and in the fused projection pipeline kernel,
+* the per-cycle step (one strided lane rotate on a periodically
+  extended accumulator), which interpret mode runs as the chip does,
+  on 128-padded lanes: bit-exact against the oracles, and its loop
+  body's shape -- in the strip, stream and fused projection pipeline
+  kernels,
 * masked final m-block + lane padding: no wrapped-duplicate garbage,
 * overflow-safe accumulators (int64 survives under x64),
 * conv routing through the dispatch.
@@ -103,29 +104,26 @@ def test_batched_kernel_wrappers_accept_2d_and_3d():
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("h", [1, 4, 13])
 def test_ladder_setup_hoisted_out_of_horner_loop(h):
-    """All (amt >> b) & 1 mask derivations (and the index-permute setup)
-    run BEFORE the fori_loop: the traced loop body contains no
-    shift-right ops, for every strip height."""
+    """All (amt >> b) & 1 mask derivations run BEFORE the fori_loop: the
+    traced loop body contains no shift-right ops, for every strip
+    height."""
     n = 13
     f = jnp.zeros((1, n, n), jnp.int32)
-    for impl in ("roll", "permute"):
-        jaxpr = str(jax.make_jaxpr(
-            lambda x, hh=h, im=impl: dprt_pallas_raw(
-                x, strip_rows=hh, m_block=8, interpret=True,
-                step_impl=im))(f))
-        loop_tok = next((t for t in ("while[", "scan[") if t in jaxpr), None)
-        assert loop_tok is not None, "Horner loop was not traced as a loop"
-        _, _, after_loop_start = jaxpr.partition(loop_tok)
-        # ALL setup (alignment masks, permute indices) is
-        # emitted before the loop; the loop body and everything after it
-        # must re-derive nothing.
-        n_shifts_total = jaxpr.count("shift_right")
-        n_shifts_after = after_loop_start.count("shift_right")
-        assert n_shifts_after == 0, (
-            f"{n_shifts_after} mask derivations inside/after the Horner "
-            f"loop (impl={impl}, H={h})")
-        # and the total setup is bounded by the two ladders' bit counts
-        assert n_shifts_total <= 2 * roll_rows_ladder_spec(n)
+    jaxpr = str(jax.make_jaxpr(
+        lambda x: dprt_pallas_raw(x, strip_rows=h, m_block=8,
+                                  interpret=True))(f))
+    loop_tok = next((t for t in ("while[", "scan[") if t in jaxpr), None)
+    assert loop_tok is not None, "Horner loop was not traced as a loop"
+    _, _, after_loop_start = jaxpr.partition(loop_tok)
+    # ALL setup (the alignment masks) is emitted before the loop; the
+    # loop body and everything after it must re-derive nothing.
+    n_shifts_total = jaxpr.count("shift_right")
+    n_shifts_after = after_loop_start.count("shift_right")
+    assert n_shifts_after == 0, (
+        f"{n_shifts_after} mask derivations inside/after the Horner "
+        f"loop (H={h})")
+    # and the total setup is bounded by the two ladders' bit counts
+    assert n_shifts_total <= 2 * roll_rows_ladder_spec(n)
 
 
 def test_ladder_setup_independent_of_strip_height():
@@ -136,21 +134,19 @@ def test_ladder_setup_independent_of_strip_height():
     for h in (1, 13):
         jaxpr = str(jax.make_jaxpr(
             lambda x, hh=h: dprt_pallas_raw(x, strip_rows=hh, m_block=8,
-                                            interpret=True,
-                                            step_impl="roll"))(f))
+                                            interpret=True))(f))
         counts.append(jaxpr.count("shift_right"))
     assert counts[0] == counts[1]
 
 
 # ---------------------------------------------------------------------------
-# the compiled step, forced in interpret mode with Mosaic's lane padding
+# the chip's step in interpret mode, on Mosaic's lane padding
 # ---------------------------------------------------------------------------
-def _roll_call(x, mode, h, mb, row_offset=None, sign=1, stream_impl=None):
-    """The chip's own per-cycle step (``step_impl="roll"``) on 128-padded
-    lanes, in interpret mode; returns the logical output as numpy."""
+def _roll_call(x, mode, h, mb, row_offset=None, sign=1, stream=False):
+    """The strip (or, with ``stream``, the DMA stream) kernel in
+    interpret mode; returns the logical output as numpy."""
     n = x.shape[-1]
-    kw = dict(mode=mode, m_block=mb, interpret=True, lane_pad=True,
-              step_impl="roll", row_offset=row_offset)
+    kw = dict(mode=mode, m_block=mb, interpret=True, row_offset=row_offset)
     if mode == "inverse":
         xa = x.astype(np.int32)
         kw["corr"] = jnp.asarray(xa[:, n] - xa[:, 0].sum(axis=1,
@@ -158,12 +154,12 @@ def _roll_call(x, mode, h, mb, row_offset=None, sign=1, stream_impl=None):
         x, kw["sign"] = x[:, :n], -1
     else:
         kw["sign"] = sign
-    if stream_impl is None:
-        out = _pallas_skew_call(jnp.asarray(x), strip_rows=h, **kw)
+    if stream:
+        out = _pallas_stream_call(jnp.asarray(x), stream_rows=h, **kw)
     else:
-        out = _pallas_stream_call(jnp.asarray(x), stream_rows=h,
-                                  stream_impl=stream_impl, **kw)
+        out = _pallas_skew_call(jnp.asarray(x), strip_rows=h, **kw)
     out = np.asarray(out)
+    assert out.shape[-1] == 128 * -(-n // 128), "lanes padded to 128"
     if mode != "inverse":     # the inverse's /N epilogue spans all lanes
         assert (out[:, :, n:] == 0).all(), "lane padding must stay zero"
     rows = n + 1 if mode == "forward" else n
@@ -171,15 +167,15 @@ def _roll_call(x, mode, h, mb, row_offset=None, sign=1, stream_impl=None):
     return out[:, :rows, :n]
 
 
-def _check_roll_modes(mode, n, h, mb, off, stream_impl=None):
+def _check_roll_modes(mode, n, h, mb, off, stream=False):
     f = rand_img(n, seed=n + h + mb, shape=(2, n, n))
     if mode == "forward":
-        got = _roll_call(f, mode, h, mb, stream_impl=stream_impl)
+        got = _roll_call(f, mode, h, mb, stream=stream)
         for i in range(2):
             np.testing.assert_array_equal(got[i], D.dprt_oracle_np(f[i]))
     elif mode == "inverse":
         r = np.stack([D.dprt_oracle_np(x) for x in f])
-        got = _roll_call(r, mode, h, mb, stream_impl=stream_impl)
+        got = _roll_call(r, mode, h, mb, stream=stream)
         for i in range(2):
             np.testing.assert_array_equal(got[i], D.idprt_oracle_np(r[i]))
             np.testing.assert_array_equal(got[i], f[i])
@@ -192,7 +188,7 @@ def _check_roll_modes(mode, n, h, mb, off, stream_impl=None):
         for sign in (1, -1):
             got = _roll_call(f[:, off:], mode, h, mb,
                              row_offset=off if off else None, sign=sign,
-                             stream_impl=stream_impl)
+                             stream=stream)
             for i in range(2):
                 np.testing.assert_array_equal(
                     got[i], np.asarray(skew_sum_ref(placed[i], sign)))
@@ -209,17 +205,16 @@ ROLL_CASES = [(5, 5, 4, None), (5, 2, 4, 1),
 @pytest.mark.parametrize("n,h,mb,off", ROLL_CASES)
 @pytest.mark.parametrize("mode", ["forward", "inverse", "core"])
 def test_roll_step_matches_oracles(mode, n, h, mb, off):
-    """The compiled step is bit-exact against the definition: forward
+    """The strided-rotate step is bit-exact against the definition: forward
     (with the fused row sum), inverse (with the exact /N) and the bare
     skew sum in both directions with a nonzero row offset."""
     _check_roll_modes(mode, n, h, mb, off)
 
 
-@pytest.mark.parametrize("stream_impl", ["grid", "dma"])
 @pytest.mark.parametrize("mode", ["forward", "inverse", "core"])
-def test_roll_step_streamed_matches_oracles(mode, stream_impl):
-    """The streamed kernels take the same step: N=13 in strips of 4."""
-    _check_roll_modes(mode, 13, 4, 5, 3, stream_impl=stream_impl)
+def test_roll_step_streamed_matches_oracles(mode):
+    """The DMA stream takes the same step: N=13 in strips of 4."""
+    _check_roll_modes(mode, 13, 4, 5, 3, stream=True)
 
 
 def _sub_jaxprs(eqn):
@@ -243,23 +238,20 @@ def _strided(eqn):
 
 
 def test_roll_step_counted_once_per_kernel_trace():
-    """``sfdprt_step_roll`` counts each kernel body traced with the
-    compiled step, and only those; an unknown step is refused."""
+    """``sfdprt_step_roll`` counts each strip or stream kernel body
+    traced: one per trace."""
     from repro.core import spans
 
     def rolls():
         return spans.snapshot()["counters"].get("sfdprt_step_roll", 0)
 
     x = jnp.zeros((1, 7, 7), jnp.int32)
-    for impl, grows in (("roll", 1), ("permute", 0)):
+    for call, kw in ((_pallas_skew_call, dict(strip_rows=7)),
+                     (_pallas_stream_call, dict(stream_rows=4))):
         before = rolls()
-        jax.make_jaxpr(lambda g, im=impl: _pallas_skew_call(
-            g, sign=1, mode="forward", strip_rows=7, m_block=8,
-            interpret=True, step_impl=im))(x)
-        assert rolls() - before == grows, impl
-    with pytest.raises(ValueError, match="step_impl"):
-        _pallas_skew_call(x, sign=1, mode="core", strip_rows=7, m_block=8,
-                          interpret=True, step_impl="ladder")
+        jax.make_jaxpr(lambda g: call(g, sign=1, mode="forward", m_block=8,
+                                      interpret=True, **kw))(x)
+        assert rolls() - before == 1, call.__name__
 
 
 @pytest.mark.parametrize("n,h", [(13, 4), (251, 251)])
@@ -272,8 +264,7 @@ def test_roll_step_loop_body_is_one_native_rotate(mode, n, h):
     corr = jnp.zeros((1, n), jnp.int32) if mode == "inverse" else None
     closed = jax.make_jaxpr(lambda g: _pallas_skew_call(
         g, sign=1 if mode == "forward" else -1, mode=mode, strip_rows=h,
-        m_block=8, interpret=True, lane_pad=True, step_impl="roll",
-        corr=corr))(x)
+        m_block=8, interpret=True, corr=corr))(x)
     loops = [e for e in _all_eqns(closed.jaxpr)
              if e.primitive.name in ("scan", "while")
              and any(_strided(b) for s in _sub_jaxprs(e)
@@ -292,7 +283,7 @@ def test_roll_step_loop_body_is_one_native_rotate(mode, n, h):
 
 
 # ---------------------------------------------------------------------------
-# the fused pipeline kernel on the compiled step
+# the fused pipeline kernel on the strided-rotate step
 # ---------------------------------------------------------------------------
 def _circ_conv_np(f, g):
     """out[x, y] = sum_{a,b} g[a, b] f[<x - a>, <y - b>]: the definition,
@@ -321,8 +312,7 @@ def _pipeline_roll(f, operand, op, form, source, mb, row_offset=None):
     out, aux = pipeline_pallas_raw(
         jnp.asarray(f), None if operand is None else jnp.asarray(operand),
         op=op, operand_form=form, source=source, m_block=mb,
-        interpret=True, step_impl="roll", row_offset=row_offset,
-        n_rows=f.shape[-1])
+        interpret=True, row_offset=row_offset, n_rows=f.shape[-1])
     out, aux = np.asarray(out, np.int64), np.asarray(aux, np.int64)
     assert out.shape[-1] == 128 * -(-f.shape[-1] // 128)
     return out, aux
@@ -340,8 +330,8 @@ PIPE_ROLL_OPS = [("conv", "image", "image"), ("conv", "proj", "image"),
 @pytest.mark.parametrize("n,mb", PIPE_ROLL_CASES)
 @pytest.mark.parametrize("op,form,source", PIPE_ROLL_OPS)
 def test_pipeline_roll_step_matches_oracles(op, form, source, n, mb):
-    """The fused pipeline kernel on the compiled step (strided lane
-    rotate Horner, 128-padded lanes) is bit-exact against the numpy
+    """The fused pipeline kernel (strided lane rotate Horner, 128-padded
+    lanes) is bit-exact against the numpy
     oracles: conv by an image or by its projections, a pointwise
     multiply by per-image weights, the round trip, and the tail over
     direction shards whose partials sum to the whole."""
@@ -383,7 +373,7 @@ def test_pipeline_roll_step_matches_oracles(op, form, source, n, mb):
                                             ("none", "proj", "proj")])
 def test_pipeline_roll_loop_bodies_are_one_native_rotate(op, form, source,
                                                          n):
-    """Every Horner loop of the pipeline on the compiled step -- the
+    """Every Horner loop of the pipeline -- the
     image's and the conv operand's forward, and each inverse sub-block --
     holds exactly one strided and one static rotate, no concatenate of
     lane slices at offsets off the 128-lane tile, and no mask
@@ -395,8 +385,8 @@ def test_pipeline_roll_loop_bodies_are_one_native_rotate(op, form, source,
     g = jnp.zeros((1, n, n), jnp.int32) if op == "conv" else None
     closed = jax.make_jaxpr(lambda a: pipeline_pallas_raw(
         a, g, op=op, operand_form=form, source=source, m_block=mb,
-        interpret=True, step_impl="roll",
-        row_offset=None if source == "image" else 8, n_rows=n))(x)
+        interpret=True, row_offset=None if source == "image" else 8,
+        n_rows=n))(x)
     loops = [e for e in _all_eqns(closed.jaxpr)
              if e.primitive.name in ("scan", "while")
              and any(_strided(b) for s in _sub_jaxprs(e)
@@ -419,9 +409,8 @@ def test_pipeline_roll_loop_bodies_are_one_native_rotate(op, form, source,
 
 
 def test_pipeline_step_roll_counted_and_refusals():
-    """``sfdprt_pipeline_step_roll`` counts each pipeline body traced
-    with the compiled step, and only those; the retired ``"ladder"``
-    step and a lane-batched compiled step are refused."""
+    """``sfdprt_pipeline_step_roll`` counts each pipeline body traced:
+    one per trace; an unknown pipeline op is refused."""
     from repro.core import spans
     from repro.kernels.sfdprt import pipeline_pallas_raw
 
@@ -430,16 +419,48 @@ def test_pipeline_step_roll_counted_and_refusals():
             "sfdprt_pipeline_step_roll", 0)
 
     x = jnp.zeros((2, 7, 7), jnp.int32)
-    for impl, grows in (("roll", 1), ("permute", 0)):
-        before = rolls()
-        jax.make_jaxpr(lambda a, im=impl: pipeline_pallas_raw(
-            a, op="none", m_block=8, step_impl=im))(x)
-        assert rolls() - before == grows, impl
-    with pytest.raises(ValueError, match="step_impl"):
-        pipeline_pallas_raw(x, op="none", m_block=8, step_impl="ladder")
-    with pytest.raises(ValueError, match="lane_batch"):
-        pipeline_pallas_raw(x, op="none", m_block=8, lane_batch=2,
-                            step_impl="roll")
+    before = rolls()
+    jax.make_jaxpr(lambda a: pipeline_pallas_raw(a, op="none",
+                                                 m_block=8))(x)
+    assert rolls() - before == 1
+    with pytest.raises(ValueError, match="pipeline op"):
+        pipeline_pallas_raw(x, op="ladder", m_block=8)
+
+
+def _interpret_default(kernel, x):
+    from repro.kernels.sfdprt import pipeline_pallas_raw
+    if kernel == "strip":
+        return dprt_pallas_raw(x, strip_rows=4, m_block=8, interpret=True)
+    if kernel == "stream":
+        return dprt_pallas_raw(x, stream_rows=4, m_block=8, interpret=True)
+    return pipeline_pallas_raw(x, x, op="conv", operand_form="image",
+                               m_block=8, interpret=True)
+
+
+@pytest.mark.parametrize("kernel", ["strip", "stream", "pipeline"])
+def test_interpret_default_takes_the_chip_step(kernel):
+    """Interpret mode, with nothing forced, traces the step the chip
+    compiles: every innermost Horner loop of the strip, stream and
+    pipeline kernels holds one strided rotate, and no kernel holds a
+    gather (``take_along_axis``).  An interpret-only step fails here."""
+    n = 13
+    closed = jax.make_jaxpr(lambda a: _interpret_default(kernel, a))(
+        jnp.zeros((1, n, n), jnp.int32))
+    calls = [e for e in _all_eqns(closed.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    eqns = [b for s in _sub_jaxprs(calls[0]) for b in _all_eqns(s)]
+    assert not any(e.primitive.name == "gather" for e in eqns)
+    loops = [e for e in eqns if e.primitive.name in ("scan", "while")
+             and not any(b.primitive.name in ("scan", "while")
+                         for s in _sub_jaxprs(e) for b in _all_eqns(s))
+             and any(b.primitive.name == "roll"
+                     for s in _sub_jaxprs(e) for b in _all_eqns(s))]
+    # a Horner per strip kernel; the pipeline's two forwards and inverse
+    assert len(loops) == (3 if kernel == "pipeline" else 1)
+    for loop in loops:
+        body = [b for s in _sub_jaxprs(loop) for b in _all_eqns(s)]
+        assert sum(map(_strided, body)) == 1, "one strided rotate a cycle"
 
 
 # ---------------------------------------------------------------------------
@@ -452,7 +473,7 @@ def test_padded_rows_and_lanes_are_zero(n):
     f = rand_img(n, seed=n, shape=(2, n, n))
     out = np.asarray(_pallas_skew_call(
         jnp.asarray(f), sign=1, mode="forward", strip_rows=3, m_block=4,
-        interpret=True, lane_pad=True))
+        interpret=True))
     assert out.shape[-1] == 128  # lane axis padded to the Mosaic tile
     for i in range(2):
         np.testing.assert_array_equal(out[i, :n + 1, :n],

@@ -29,15 +29,14 @@ def _capable_backends():
 # ---------------------------------------------------------------------------
 # kernel level
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("n,m_block,group,lane_batch",
-                         [(5, 4, 1, 1), (7, 8, 3, 2), (13, 4, 8, 3),
-                          (13, 16, 4, 1)])
-def test_pipeline_kernel_conv_matches_oracle(n, m_block, group, lane_batch):
+@pytest.mark.parametrize("n,m_block,group",
+                         [(5, 4, 1), (7, 8, 3), (13, 4, 8), (13, 16, 4)])
+def test_pipeline_kernel_conv_matches_oracle(n, m_block, group):
     rng = np.random.default_rng(n)
     fb = jnp.asarray(rng.integers(0, 30, (3, n, n)), jnp.int32)
     g = jnp.asarray(rng.integers(0, 9, (n, n)), jnp.int32)
     out = projection_pipeline_pallas(fb, "conv", g, m_block=m_block,
-                                     group=group, lane_batch=lane_batch)
+                                     group=group)
     for i in range(3):
         np.testing.assert_array_equal(
             np.asarray(out[i], np.int64),
@@ -45,12 +44,11 @@ def test_pipeline_kernel_conv_matches_oracle(n, m_block, group, lane_batch):
     # round trip (op="none") and all-ones pointwise weights == identity
     np.testing.assert_array_equal(
         np.asarray(projection_pipeline_pallas(
-            fb, "none", m_block=m_block, group=group,
-            lane_batch=lane_batch)), np.asarray(fb))
+            fb, "none", m_block=m_block, group=group)), np.asarray(fb))
     np.testing.assert_array_equal(
         np.asarray(projection_pipeline_pallas(
             fb, "mul", jnp.ones((n + 1, n), jnp.int32), m_block=m_block,
-            group=group, lane_batch=lane_batch)), np.asarray(fb))
+            group=group)), np.asarray(fb))
 
 
 def test_pipeline_kernel_operand_forms_agree():
@@ -401,15 +399,12 @@ def test_pipeline_trace_counting_and_retrace_guard():
             C.circ_conv2d_dprt(f + 1, g)
 
 
-@pytest.mark.parametrize("step_impl", [None, "roll"])
 @pytest.mark.parametrize("n", [31, 61])
-def test_conv2d_u8_stack_matches_oracle(n, step_impl, conv251_kernel,
-                                        pipeline_step):
+def test_conv2d_u8_stack_matches_oracle(n, conv251_kernel, pipeline_step):
     """The 2-D filtering deployment's entry, ``radon.Conv2D`` of uint8
     stacks by its 5x5 kernel, compiled through the plan to the fused
-    pipeline kernel: bit for bit the numpy oracle, on the interpret
-    default step and on the chip's ``"roll"`` step."""
-    pipeline_step(step_impl)
+    pipeline kernel: bit for bit the numpy oracle."""
+    pipeline_step()
     rng = np.random.default_rng(n)
     x = rng.integers(0, 256, (4, n, n), dtype=np.uint8)
     exe = radon.Conv2D((4, n, n), jnp.asarray(conv251_kernel),

@@ -2,9 +2,9 @@
 direction-sharded collective layout.
 
 The streamed kernels process an N x N image that does not fit
-whole-image-in-VMEM as ONE ``pallas_call``: the grid (or an in-kernel
-DMA double-buffer loop) walks row strips and accumulates partial
-skew-sums in a VMEM scratch accumulator.  Everything here must stay
+whole-image-in-VMEM as ONE ``pallas_call``: an in-kernel DMA
+double-buffer loop walks row strips and accumulates partial skew-sums
+in registers.  Everything here must stay
 bit-exact against the whole-image kernel and the numpy oracle --
 including awkward primes where the strip count does not divide N (the
 final strip is masked padding).
@@ -30,34 +30,29 @@ def _img(n, b=None, seed=0, lo=0, hi=250):
 
 
 # ---------------------------------------------------------------------------
-# streamed vs whole-image: bit-exact, both stream impls, partial strips
+# streamed vs whole-image: bit-exact, partial strips
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("stream_impl", ["grid", "dma"])
 @pytest.mark.parametrize("n,sr", [(13, 5), (61, 7)])
-def test_streamed_raw_kernels_bitexact(n, sr, stream_impl):
+def test_streamed_raw_kernels_bitexact(n, sr):
     """Raw streamed kernels == whole-image kernels == oracle, at strip
     heights that do NOT divide N (final strip is a masked partial)."""
     assert n % sr != 0, "test wants a masked final strip"
     fb = jnp.asarray(_img(n, b=3, seed=n))
     whole = dprt_pallas_raw(fb, strip_rows=n, m_block=8)
-    got = dprt_pallas_raw(fb, stream_rows=sr, m_block=8,
-                          stream_impl=stream_impl)
+    got = dprt_pallas_raw(fb, stream_rows=sr, m_block=8)
     assert (np.asarray(got) == np.asarray(whole)).all()
     for b in range(3):
         assert (np.asarray(got[b]) == dprt_oracle_np(np.asarray(fb[b]))).all()
-    back = idprt_pallas_raw(got, stream_rows=sr, m_block=8,
-                            stream_impl=stream_impl)
+    back = idprt_pallas_raw(got, stream_rows=sr, m_block=8)
     assert (np.asarray(back) == np.asarray(fb)).all()
     # bare skew-sum, both signs (adjoint datapaths ride this)
     for sign in (1, -1):
         want = skew_sum_pallas_raw(fb, sign, strip_rows=n, m_block=8)
-        got = skew_sum_pallas_raw(fb, sign, stream_rows=sr, m_block=8,
-                                  stream_impl=stream_impl)
+        got = skew_sum_pallas_raw(fb, sign, stream_rows=sr, m_block=8)
         assert (np.asarray(got) == np.asarray(want)).all(), sign
 
 
-@pytest.mark.parametrize("stream_impl", ["grid", "dma"])
-def test_streamed_row_offset_partials(stream_impl):
+def test_streamed_row_offset_partials():
     """A streamed partial over a shard-local strip (row_offset) matches
     the fused strip kernel -- the contract the sharded backend uses."""
     n, rows, off = 13, 6, 7
@@ -65,8 +60,52 @@ def test_streamed_row_offset_partials(stream_impl):
     want = skew_sum_pallas_raw(g, 1, strip_rows=rows, m_block=8,
                                row_offset=off)
     got = skew_sum_pallas_raw(g, 1, stream_rows=4, m_block=8,
-                              row_offset=off, stream_impl=stream_impl)
+                              row_offset=off)
     assert (np.asarray(got) == np.asarray(want)).all()
+
+
+# (N, stream_rows): the last strip is ragged; (mode, row_offset): a row
+# strip from global row 9 is a shard-local partial (the inverse takes
+# whole projections only)
+RAGGED = [(31, 8), (127, 16)]
+RAGGED_MODES = [("forward", None), ("forward", 9), ("inverse", None),
+                ("core", None), ("core", 9)]
+
+
+@pytest.mark.parametrize("mode,off", RAGGED_MODES)
+@pytest.mark.parametrize("n,sr", RAGGED)
+def test_dma_stream_ragged_last_strip(n, sr, mode, off):
+    """The DMA stream with a ragged last strip, in each mode and with or
+    without a row offset, is bit-exact against the numpy oracles (a
+    partial's oracle is the whole image with the rows above the offset
+    zeroed)."""
+    from repro.core.dprt import idprt_oracle_np
+    from repro.kernels.ref import skew_sum_ref
+    f = _img(n, b=2, seed=n + sr)
+    rows = f[:, off or 0:]
+    assert rows.shape[1] % sr != 0, "test wants a ragged last strip"
+    placed = f.copy()
+    placed[:, :off or 0] = 0
+    if mode == "inverse":
+        r = np.stack([dprt_oracle_np(x) for x in f]).astype(np.int32)
+        got = np.asarray(idprt_pallas_raw(jnp.asarray(r), stream_rows=sr,
+                                          m_block=32))
+        for b in range(2):
+            np.testing.assert_array_equal(got[b], idprt_oracle_np(r[b]))
+            np.testing.assert_array_equal(got[b], f[b])
+    elif mode == "forward":
+        got = np.asarray(dprt_pallas_raw(jnp.asarray(rows), stream_rows=sr,
+                                         m_block=32, row_offset=off))
+        for b in range(2):
+            np.testing.assert_array_equal(got[b], dprt_oracle_np(placed[b]))
+    else:
+        for sign in (1, -1):
+            got = np.asarray(skew_sum_pallas_raw(
+                jnp.asarray(rows), sign, stream_rows=sr, m_block=32,
+                row_offset=off))
+            for b in range(2):
+                np.testing.assert_array_equal(
+                    got[b], np.asarray(skew_sum_ref(placed[b], sign)))
 
 
 def test_streamed_plan_roundtrip_and_adjoint():
@@ -167,14 +206,12 @@ def _jaxpr_stats(fn, x):
     return pallas_found, counter[0]
 
 
-@pytest.mark.parametrize("stream_impl", ["grid", "dma"])
-def test_streamed_is_one_launch_constant_size(stream_impl):
+def test_streamed_is_one_launch_constant_size():
     n = 61
     fb = jnp.asarray(_img(n, b=1, seed=6))
 
     def fwd(sr):
-        return lambda x: dprt_pallas_raw(x, stream_rows=sr, m_block=8,
-                                         stream_impl=stream_impl)
+        return lambda x: dprt_pallas_raw(x, stream_rows=sr, m_block=8)
 
     found, size_a = _jaxpr_stats(fwd(4), fb)
     assert len(found) == 1, "streamed forward must be ONE pallas_call"

@@ -145,6 +145,81 @@ def test_conv2d_u8_compiles_b64_n251(one_chip, conv251_kernel,
                      "sfdprt_pipeline_ladder": 0})
 
 
+#: lowers ``radon.DPRT((256, 251, 251), uint8)`` (the ``dprt251.batch``
+#: cell's executable) or its inverse for the described chip in a fresh
+#: process and prints the sha256 of the lowered text
+_LOWER_DPRT251 = """
+import hashlib, os, sys
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, {tests!r})
+import jax
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("no-topology", e)
+    raise SystemExit(0)
+jax.default_backend = lambda: "tpu"   # the wrappers' interpret choice
+import test_tpu_compile as t
+text = t.lower_dprt251(SingleDeviceSharding(topo.devices[0]), {inverse})
+print("sha256", hashlib.sha256(text.encode()).hexdigest())
+"""
+
+
+def lower_dprt251(sharding, inverse: bool) -> str:
+    """The lowered text of the ``dprt251.batch`` cell's forward (or
+    inverse) executable, for the chip that ``sharding`` names."""
+    op = radon.DPRT((256, 251, 251), jnp.uint8)
+    if inverse:
+        op = op.inverse
+    aval = jax.ShapeDtypeStruct(op.shape_in, op.dtype_in, sharding=sharding)
+    text = jax.jit(lambda f: op(f)).lower(aval).as_text()
+    assert "tpu_custom_call" in text and "sfdprt_" in text
+    return text
+
+
+@pytest.mark.parametrize("direction", ["forward", "inverse"])
+def test_dprt251_lowering_same_in_every_process(subproc, request,
+                                                monkeypatch, direction):
+    """The benchmark cell's strip kernels lower to the same text in two
+    fresh processes: nothing that varies from process to process (an
+    id, a hash seed, a time, a temp path) reaches the lowered module,
+    whose Mosaic payload is part of JAX's persistent compile-cache key.
+    Were it to vary, every run would miss that cache and recompile.
+
+    The children load the TPU compiler beside this process, which works
+    where ``ALLOW_MULTIPLE_LIBTPU_LOAD=1`` is set.  Where a child cannot
+    describe the chip, the test lowers twice in its own process instead,
+    with every plan, executable and trace cache cleared in between: that
+    still catches anything drawn from a counter or an id, though not
+    from the hash seed."""
+    inverse = direction == "inverse"
+    code = _LOWER_DPRT251.format(
+        inverse=inverse, tests=os.path.dirname(os.path.abspath(__file__)))
+    texts = []
+    for _ in range(2):
+        out = subproc(code, devices=1)
+        if "no-topology" in out:
+            break
+        texts += [ln.split()[1] for ln in out.splitlines()
+                  if ln.startswith("sha256 ")]
+    if len(texts) < 2:
+        from repro.kernels import sfdprt
+        one_chip = request.getfixturevalue("one_chip")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        texts = []
+        for _ in range(2):
+            radon.aot_cache_clear()
+            radon.plan_cache_clear()
+            sfdprt.dprt_pallas_raw.clear_cache()
+            sfdprt.idprt_pallas_raw.clear_cache()
+            jax.clear_caches()
+            texts.append(lower_dprt251(one_chip, inverse))
+    assert len(texts) == 2 and texts[0] == texts[1]
+
+
 def test_sharded_strip_kernel_compiles_on_2x2(topo, monkeypatch):
     """The per-shard strip kernel under ``shard_map``: its traced
     ``row_offset`` (axis_index * rows per device) feeds the alignment
