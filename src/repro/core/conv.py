@@ -123,9 +123,8 @@ def _circ_prime(f: jnp.ndarray, g: jnp.ndarray, knobs: tuple,
 
     Fused route (pipeline-capable backends): transform, per-direction
     1-D circular convolution and inverse as ONE kernel launch -- the
-    projections never round-trip through HBM.  A batched stack against
-    one shared kernel precomputes the kernel's projections with a single
-    small forward launch and rides the batched pipeline.  Staged route:
+    projections never round-trip through HBM; a kernel shared by a
+    stack is forwarded once by the backend's pipeline.  Staged route:
     forward both operands, 1-D convolve (circulant built from the
     unbatched side only), inverse.
     """
@@ -138,11 +137,7 @@ def _circ_prime(f: jnp.ndarray, g: jnp.ndarray, knobs: tuple,
     if _use_pipeline(plan, fuse):
         if g.ndim > f.ndim:      # convolution commutes: pipeline the stack
             return _circ_prime(g, f, knobs, fuse)
-        if f.ndim == 3 and g.ndim == 2:
-            # one shared operand for a whole stack: its projections are
-            # computed ONCE (one small fused forward) and broadcast
-            return pipeline_apply(plan, f, "conv", fwd(g))
-        return pipeline_apply(plan, f, "conv", g)     # in-kernel operand
+        return pipeline_apply(plan, f, "conv", g)
     rf, rg = fwd(f), fwd(g)
     rc = circ_conv1d_exact(rf, rg)      # all N+1 directions at once
     n = rc.shape[-1]

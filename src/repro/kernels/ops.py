@@ -26,6 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.core.dprt import accum_dtype_for, is_prime
 from .sfdprt import (PIPELINE_OPS, dprt_pallas_raw, idprt_pallas_raw,
                      pipeline_pallas_raw, skew_sum_pallas_raw)
@@ -193,8 +194,10 @@ def projection_pipeline_pallas(f, op: str = "conv", operand=None,
       exact 2-D circular convolution.  ``operand`` is the other image
       ((N, N) shared or (B, N, N) matched; its forward runs in-kernel)
       or its precomputed projections ((N+1, N) / (B, N+1, N)) with
-      ``operand_form="proj"`` -- the form batched callers use so one
-      small forward launch is shared by the whole stack.
+      ``operand_form="proj"``.  An image shared by a stack of more than
+      one image is forwarded once here, by one small forward launch,
+      and the kernel takes its projections: every grid step would
+      otherwise forward the same operand again.
     * ``"mul"``  -- pointwise projection-domain multiply by an
       (N+1, N) / (B, N+1, N) weight array (``inv @ pointwise @ fwd``
       operator fusion).
@@ -234,6 +237,11 @@ def projection_pipeline_pallas(f, op: str = "conv", operand=None,
                 f"({fb.shape[0]}), got {operand.shape}")
         wb = wb.astype(acc)
     interp = _auto_interpret(interpret)
+    if op == "conv" and operand_form == "image" and wb.shape[0] == 1 \
+            and fb.shape[0] > 1:
+        spans.count("sfdprt_pipeline_operand_shared")
+        wb = dprt_pallas(wb, interpret=interp).astype(acc)
+        operand_form = "proj"
     mb, grp = resolve_pipeline_blocks(n, jnp.dtype(acc).itemsize,
                                       m_block, group)
     out, _aux = pipeline_pallas_raw(fb.astype(acc), wb, op=op,

@@ -8,6 +8,7 @@ import pytest
 
 from repro.core import conv as C
 from repro.core import dft as F
+from repro.core import spans
 from repro.core.dprt import dprt_oracle_np
 from repro.core.plan import available_backends, backend_capabilities, \
     get_backend, get_plan
@@ -417,6 +418,81 @@ def test_conv2d_u8_stack_matches_oracle(n, conv251_kernel, pipeline_step):
         np.testing.assert_array_equal(
             out[i], np.asarray(C.circ_conv2d_direct(
                 jnp.asarray(x[i], jnp.int32), g)))
+
+
+def _counters() -> dict:
+    return dict(spans.snapshot()["counters"])
+
+
+def _grew(before: dict, key: str) -> int:
+    return _counters().get(key, 0) - before.get(key, 0)
+
+
+def test_conv2d_shared_kernel_stack_forwards_it_once(pipeline_step):
+    """A stack against one shared kernel: the kernel's forward is hoisted
+    out of the pipeline kernel's grid (one launch a stack, counted), and
+    the result is bit for bit the oracle and the per-image operator."""
+    pipeline_step()
+    n = 13
+    rng = np.random.default_rng(21)
+    x = jnp.asarray(rng.integers(0, 256, (4, n, n)), jnp.int32)
+    kern = jnp.asarray(rng.integers(0, 9, (5, 5)), jnp.int32)
+    before = _counters()
+    out = np.asarray(radon.Conv2D((4, n, n), kern)(x))
+    assert _grew(before, "sfdprt_pipeline_operand_shared") >= 1
+    assert _grew(before, "sfdprt_pipeline_operand_fwd") == 0
+    g = jnp.pad(kern, ((0, n - 5), (0, n - 5)))
+    one = radon.Conv2D((n, n), kern)
+    for i in range(4):
+        np.testing.assert_array_equal(
+            out[i].astype(np.int64),
+            np.asarray(C.circ_conv2d_direct(x[i], g)))
+        np.testing.assert_array_equal(out[i], np.asarray(one(x[i])))
+
+
+@pytest.mark.parametrize("case", ["single", "per_image"])
+def test_pipeline_operand_forward_stays_in_kernel(case, pipeline_step):
+    """One image, or one operand per image: the operand's forward still
+    runs in the kernel's grid step (the work is the same there), and the
+    result is the oracle's."""
+    pipeline_step()
+    n = 13
+    rng = np.random.default_rng(22)
+    b = 1 if case == "single" else 3
+    fb = jnp.asarray(rng.integers(0, 30, (b, n, n)), jnp.int32)
+    shape = (n, n) if case == "single" else (b, n, n)
+    g = jnp.asarray(rng.integers(0, 9, shape), jnp.int32)
+    before = _counters()
+    out = projection_pipeline_pallas(fb, "conv", g)
+    assert _grew(before, "sfdprt_pipeline_operand_fwd") == 1
+    assert _grew(before, "sfdprt_pipeline_operand_shared") == 0
+    gb = jnp.broadcast_to(g, (b, n, n))
+    for i in range(b):
+        np.testing.assert_array_equal(
+            np.asarray(out[i], np.int64),
+            np.asarray(C.circ_conv2d_direct(fb[i], gb[i])))
+
+
+def test_batched_conv2d_grad_wrt_kernel_is_exact_adjoint():
+    """``kernel=`` differentiation of a batched ``Conv2D`` (the shared
+    kernel forwarded once a stack) equals the exact adjoint."""
+    rng = np.random.default_rng(23)
+    n, b = 7, 3
+    f = jnp.asarray(rng.integers(-4, 5, (b, n, n)), jnp.float32)
+    u = jnp.asarray(rng.integers(-4, 5, (b, n, n)), jnp.float32)
+    kern = jnp.asarray(rng.integers(-4, 5, (n, n)), jnp.float32)
+    gk = jax.grad(lambda k: (radon.Conv2D((b, n, n), k, jnp.float32,
+                                          "pallas")(f) * u).sum())(kern)
+    dense_g = np.zeros((b * n * n, n * n))
+    for j in range(n * n):
+        e = np.zeros((n, n), np.float32)
+        e.flat[j] = 1
+        dense_g[:, j] = np.concatenate([
+            np.asarray(C.circ_conv2d_direct(f[i], jnp.asarray(e))).ravel()
+            for i in range(b)])
+    np.testing.assert_allclose(np.asarray(gk).ravel(),
+                               dense_g.T @ np.asarray(u).ravel(),
+                               rtol=1e-6, atol=1e-4)
 
 
 def test_fused_composite_aot_survives_plan_cache_clear():

@@ -213,10 +213,10 @@ def test_router_healthz_ends_with_set_up_spans():
 def test_conv2d_compile_credits_pipeline_counters(conv251_kernel,
                                                   pipeline_step):
     """One ``Conv2D`` compile credits the fused pipeline kernel's
-    counters to its ``radon.compile``: the in-kernel forward of the
-    conv operand (the kernel embeds as an image operand) and the
-    strided-rotate step, which every body takes; both show on its
-    ``built`` line."""
+    counters to its ``radon.compile``: the shared kernel's forward,
+    hoisted out of the kernel's grid for the whole stack (no in-kernel
+    forward of the operand), and the strided-rotate step, which every
+    body takes; both show on its ``built`` line."""
     pipeline_step()
     t0 = time.perf_counter_ns()
     radon.Conv2D((2, 13, 13), jnp.asarray(conv251_kernel),
@@ -225,10 +225,12 @@ def test_conv2d_compile_credits_pipeline_counters(conv251_kernel,
     assert len(compiles) == 1
     assert compiles[0]["attrs"]["kind"] == "conv2d"
     credits = compiles[0]["credits"]
-    assert credits.get("sfdprt_pipeline_operand_fwd", 0) >= 1
+    assert credits.get("sfdprt_pipeline_operand_shared", 0) >= 1
+    assert credits.get("sfdprt_pipeline_operand_fwd", 0) == 0
     assert credits.get("sfdprt_pipeline_step_roll", 0) >= 1
     built = [ln for ln in healthz.span_lines()
              if "built radon.compile" in ln and "kind=conv2d" in ln]
-    assert "sfdprt_pipeline_operand_fwd=" in built[-1]
+    assert "sfdprt_pipeline_operand_shared=" in built[-1]
+    assert "sfdprt_pipeline_operand_fwd" not in built[-1]
     assert "sfdprt_pipeline_step_roll=" in built[-1]
     assert "sfdprt_pipeline_ladder" not in built[-1]

@@ -115,23 +115,25 @@ def test_dma_stream_compiles_n2053(one_chip, direction):
 @pytest.mark.parametrize("op", ["conv", "mul"])
 def test_pipeline_compiles(one_chip, op, n):
     """The fused projection pipeline with its tuned direction block:
-    ``conv`` against an image operand (its forward runs in-kernel) and
-    ``mul`` against shared projection-domain weights."""
+    ``conv`` against one image operand per image (its forward runs
+    in-kernel) and ``mul`` against shared projection-domain weights."""
     f = jax.ShapeDtypeStruct((2, n, n), jnp.int32, sharding=one_chip)
-    rows = n if op == "conv" else n + 1
-    w = jax.ShapeDtypeStruct((rows, n), jnp.int32, sharding=one_chip)
+    shape = (2, n, n) if op == "conv" else (n + 1, n)
+    w = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
     _compile(lambda x, y: projection_pipeline_pallas(x, op, y,
                                                      interpret=False), f, w,
              name=f"sfdprt_pipeline_{op}",
-             counts={"sfdprt_pipeline_step_roll": 1})
+             counts={"sfdprt_pipeline_step_roll": 1,
+                     "sfdprt_pipeline_operand_fwd": int(op == "conv")})
 
 
 def test_conv2d_u8_compiles_b64_n251(one_chip, conv251_kernel,
                                      pipeline_step, monkeypatch):
     """The 2-D filtering deployment's entry at its own shape,
     ``radon.Conv2D((64, 251, 251), 5x5 kernel, uint8)``, through the
-    plan to one fused pipeline kernel: its body traced once, with the
-    strided-rotate step and the conv operand's in-kernel forward."""
+    plan to the shared kernel's one forward launch and one fused
+    pipeline kernel: its body traced once, with the strided-rotate step
+    and the operand's projections in place of its in-kernel forward."""
     pipeline_step()
     # the kernel wrappers pick interpret mode from the process's backend
     # (the CPU here); steer them to the chip's lowering
@@ -139,10 +141,13 @@ def test_conv2d_u8_compiles_b64_n251(one_chip, conv251_kernel,
     op = radon.Conv2D((64, 251, 251), jnp.asarray(conv251_kernel),
                       jnp.uint8)
     aval = jax.ShapeDtypeStruct(op.shape_in, jnp.uint8, sharding=one_chip)
-    _compile(lambda f: op(f), aval, name="sfdprt_pipeline_conv",
-             counts={"sfdprt_pipeline_step_roll": 1,
-                     "sfdprt_pipeline_operand_fwd": 1,
-                     "sfdprt_pipeline_ladder": 0})
+    compiled = _compile(lambda f: op(f), aval, name="sfdprt_pipeline_conv",
+                        counts={"sfdprt_pipeline_step_roll": 1,
+                                "sfdprt_pipeline_operand_fwd": 0,
+                                "sfdprt_pipeline_operand_shared": 1,
+                                "sfdprt_step_roll": 1,
+                                "sfdprt_pipeline_ladder": 0})
+    assert "sfdprt_forward" in compiled.as_text()
 
 
 #: lowers ``radon.DPRT((256, 251, 251), uint8)`` (the ``dprt251.batch``
